@@ -36,7 +36,11 @@ def main() -> None:
     args = ap.parse_args()
     counts = [int(c) for c in args.device_counts.split(",")]
 
-    # must precede the first jax import anywhere in this process
+    # must precede the first jax import anywhere in this process. The
+    # simulated devices are CPU devices: on an accelerator host the parent
+    # bench process already holds the chip, which one process owns at a
+    # time, so the child never asks for it
+    os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={max(counts)} "
         + os.environ.get("XLA_FLAGS", ""))

@@ -9,6 +9,8 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.compile_cache import enable_compile_cache  # noqa: E402
+
 from repro.core.abstraction import HitGNN
 from repro.data.graphs import scaled_dataset
 from repro.configs.gnn import DATASETS, GNNModelConfig
@@ -51,4 +53,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -11,6 +11,8 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.compile_cache import enable_compile_cache  # noqa: E402
+
 import numpy as np
 
 from repro.configs.gnn import GNNModelConfig, PlatformConfig
@@ -71,4 +73,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -13,6 +13,8 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.compile_cache import enable_compile_cache  # noqa: E402
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -72,4 +74,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -19,6 +19,8 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.compile_cache import enable_compile_cache  # noqa: E402
+
 from repro.data.graphs import scaled_dataset
 from repro.configs.gnn import GNNModelConfig, PlatformConfig
 from repro.checkpoint.checkpointing import Checkpointer
@@ -60,4 +62,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

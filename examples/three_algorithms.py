@@ -14,6 +14,8 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.compile_cache import enable_compile_cache  # noqa: E402
+
 from repro.data.graphs import scaled_dataset
 from repro.configs.gnn import GNNModelConfig, PlatformConfig
 from repro.gnn import train
@@ -39,4 +41,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -40,7 +40,7 @@ from __future__ import annotations
 import threading
 import time
 from concurrent.futures import Future
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 from queue import Empty, Queue
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -54,6 +54,8 @@ from repro.core.sampler import (NeighborSampler, layer_capacities_for,
 from repro.core.sampler_pool import SamplerPool
 from repro.core.scheduling import BatchTask, SchedulingCore
 from repro.data.graphs import Graph
+from repro.kernels.layout import (EDGE_STREAM_BACKENDS, block_capacities,
+                                  build_layer_layouts)
 
 # RNG epoch coordinate reserved for serving streams — far above any
 # realistic training epoch count, so (seed, partition, epoch, tag) streams
@@ -279,6 +281,19 @@ class ServingRuntime:
             fn = self._fwd[bucket] = jax.jit(fwd)
         return fn
 
+    def _bucket_layout(self, bucket: int, mb) -> dict:
+        """The stage-2b layout a kernel aggregate backend consumes, at the
+        bucket's static capacities (the trainer's planner with
+        ``batch_targets=bucket``); empty for the reference path and GAT."""
+        from repro.gnn.models import AGG_KIND, KERNEL_BACKENDS
+        kind = AGG_KIND[self.cfg.name]
+        if self.cfg.aggregate_backend not in KERNEL_BACKENDS or kind is None:
+            return {}
+        caps = block_capacities(replace(self.cfg, batch_targets=bucket))
+        return build_layer_layouts(
+            mb.edge_src, mb.edge_dst, mb.edge_mask, caps, kind,
+            edge_stream=self.cfg.aggregate_backend in EDGE_STREAM_BACKENDS)
+
     @property
     def forward_compiles(self) -> int:
         """Compiled-executable count across the bucket forwards — flat
@@ -335,6 +350,7 @@ class ServingRuntime:
             t0 = time.perf_counter()
             feats = self.store.gather(0, mb.nodes[0], mb.node_mask[0])
             arrs = self._trainer_mod.batch_to_arrays(mb, feats)
+            arrs.update(self._bucket_layout(bucket, mb))
             logits = self._forward_for(bucket)(self.params, arrs)
             logits = np.asarray(jax.block_until_ready(logits))
             self.batcher.observe(bucket, time.perf_counter() - t0)

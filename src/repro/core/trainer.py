@@ -97,6 +97,16 @@ def batch_to_arrays(mb: MiniBatch, feats: Optional[np.ndarray]) -> dict:
     }
 
 
+def _arg_spec(x) -> jax.ShapeDtypeStruct:
+    """Shape, dtype and — for arrays laid across several devices — the
+    sharding of one step argument (single-device arrays may still be
+    uncommitted: their placement follows the others')."""
+    sharding = getattr(x, "sharding", None)
+    if sharding is not None and len(sharding.device_set) < 2:
+        sharding = None
+    return jax.ShapeDtypeStruct(np.shape(x), x.dtype, sharding=sharding)
+
+
 def stack_batches(batches: List[dict]) -> dict:
     return jax.tree.map(lambda *xs: np.stack(xs), *batches)
 
@@ -283,6 +293,7 @@ class SyncGNNTrainer:
         # opt state / the feature shard are NOT donated (persistent), and
         # donation cannot change values: tests pin the step bitwise at p=1.
         self._jit_step = jax.jit(self._make_step(), donate_argnums=(2,))
+        self._step_specs = None  # arg shapes of the first dispatched step
         # static block-CSR capacities per layer (pallas aggregate backend):
         # one shape per config => one compiled executable across the epoch
         # (kernels/layout.block_capacities — SHARED with the sampler-pool
@@ -401,7 +412,7 @@ class SyncGNNTrainer:
             # per-batch loss weights: real batches 1.0, idle-device fill
             # batches 0.0 — the weighted mean keeps sync-SGD semantics equal
             # to averaging over only the REAL batches of the iteration.
-            # Grads are taken PER DEVICE inside the vmap and combined with
+            # Grads are taken PER DEVICE slot and combined with
             # one explicit weighted contraction (mirroring the mesh step's
             # per-device grads + psum) rather than differentiating the
             # weighted mean directly: the latter lets jax fold the device
@@ -418,7 +429,10 @@ class SyncGNNTrainer:
                     per_device_loss, has_aux=True)(params, b)
                 return l, m, g
 
-            losses, metrics, per_dev = jax.vmap(device_val_grad)(stacked)
+            # one slot after another (lax.map, not vmap): a batched
+            # pallas_call cannot block its HBM-resident (memory_space=ANY)
+            # operands, so the Mosaic kernels compile only unbatched
+            losses, metrics, per_dev = jax.lax.map(device_val_grad, stacked)
             loss = (losses * w).sum() / w_sum
             grads = jax.tree.map(
                 lambda g: jnp.tensordot(w, g, axes=1) / w_sum, per_dev)
@@ -442,7 +456,6 @@ class SyncGNNTrainer:
         the vmap step's weighted mean, so idle-device fill batches (weight
         0) still contribute nothing; the optimizer update runs outside the
         shard_map on the replicated gradient."""
-        from jax.experimental.shard_map import shard_map
         p3 = self.algorithm == "p3"
         feat_dim = self.graph.features.shape[1]
 
@@ -465,10 +478,10 @@ class SyncGNNTrainer:
             acc = jax.lax.psum(metrics["acc"] * w, "data") / w_sum
             return grads, loss, acc
 
-        sharded_grads = shard_map(
+        sharded_grads = jax.shard_map(
             device_grads, mesh=self.mesh,
             in_specs=(P(), P("data"), P(), P("data")),
-            out_specs=(P(), P(), P()), check_rep=False)
+            out_specs=(P(), P(), P()), check_vma=False)
 
         def step(params, opt_state, stacked, repl, vshard, err):
             grads, loss, acc = sharded_grads(params, stacked, repl, vshard)
@@ -743,19 +756,29 @@ class SyncGNNTrainer:
                                     prepared.get("repl", {}))
             if self._shard is None:
                 self._upload_shards()
-            (self.params, self.opt_state, self._err,
-             metrics) = self._jit_step(self.params, self.opt_state, stacked,
-                                       repl_ops, self._shard, self._err)
+            args = (self.params, self.opt_state, stacked, repl_ops,
+                    self._shard, self._err)
         else:
-            (self.params, self.opt_state, self._err,
-             metrics) = self._jit_step(self.params, self.opt_state, stacked,
-                                       self._err)
+            args = (self.params, self.opt_state, stacked, self._err)
+        if self._step_specs is None:  # one shape per config
+            self._step_specs = jax.tree.map(_arg_spec, args)
+        (self.params, self.opt_state, self._err,
+         metrics) = self._jit_step(*args)
         self.step_no += 1
         if not sync:
             return metrics
         out = {k: float(v) for k, v in metrics.items()}
         out["vertices_traversed"] = prepared["vertices"]
         return out
+
+    def compiled_step_text(self) -> str:
+        """Optimized HLO text of the jitted step at the shapes it runs
+        with: which Pallas kernels (``tpu_custom_call``) and collectives
+        (``all-reduce``, ``all-to-all``) the compiler put in. Needs one
+        dispatched step; the persistent compile cache makes it cheap."""
+        if self._step_specs is None:
+            raise RuntimeError("no step has been dispatched yet")
+        return self._jit_step.lower(*self._step_specs).compile().as_text()
 
     def run_iteration(self, assignments: List[sched.Assignment]) -> dict:
         return self._execute(self._prepare_group(assignments))

@@ -126,11 +126,13 @@ def make_data_mesh(num_devices: int) -> Mesh:
     if num_devices < 1:
         raise ValueError(f"num_devices must be >= 1, got {num_devices}")
     if avail < num_devices:
+        backend = jax.default_backend()
+        hint = (f"; on a CPU host simulate devices with XLA_FLAGS="
+                f"--xla_force_host_platform_device_count={num_devices} "
+                f"(set BEFORE jax is imported)" if backend == "cpu" else "")
         raise ValueError(
             f"data-parallel mesh needs {num_devices} devices but this "
-            f"process has {avail}; on a CPU host simulate devices with "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count="
-            f"{num_devices} (set BEFORE jax is imported)")
+            f"process has {avail} {backend} device(s){hint}")
     return Mesh(np.array(jax.devices()[:num_devices]), ("data",))
 
 

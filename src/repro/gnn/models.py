@@ -36,9 +36,13 @@ AGG_KIND = {"graphsage": "mean", "gcn": "mean", "gin": "sum", "gat": None}
 
 
 def _mul_host(a, b):
-    """Single-rounding elementwise product, evaluated on the host."""
-    out = np.multiply(np.asarray(a), np.asarray(b))
-    return np.asarray(out, dtype=np.asarray(b).dtype)
+    """Single-rounding elementwise product, evaluated on the host. Under
+    vmap (``vmap_method="broadcast_all"``) a scalar ``a`` arrives as one
+    value per batch entry, so its trailing axes are padded to broadcast
+    against ``b``."""
+    a, b = np.asarray(a), np.asarray(b)
+    a = a.reshape(a.shape + (1,) * (b.ndim - a.ndim))
+    return np.asarray(np.multiply(a, b), dtype=b.dtype)
 
 
 def _pinned_mul(a: jax.Array, b: jax.Array) -> jax.Array:
@@ -54,7 +58,7 @@ def _pinned_mul(a: jax.Array, b: jax.Array) -> jax.Array:
     """
     return jax.pure_callback(
         _mul_host, jax.ShapeDtypeStruct(b.shape, b.dtype), a, b,
-        vectorized=True)
+        vmap_method="broadcast_all")
 
 
 @jax.custom_vjp
@@ -241,9 +245,15 @@ def _layer(cfg: GNNModelConfig, p, h, batch, l: int, n_dst: int):
     src, dst = batch["edge_src"][l], batch["edge_dst"][l]
     emask = batch["edge_mask"][l]
     h_self = h[batch["self_idx"][l]]
+    # GAT's attention weights are device-computed: it always runs the
+    # reference edge-list path, whatever the backend
     use_kernel = (cfg.aggregate_backend in KERNEL_BACKENDS
-                  and AGG_KIND.get(cfg.name) is not None
-                  and "agg_tile_off" in batch)
+                  and AGG_KIND.get(cfg.name) is not None)
+    if use_kernel and "agg_tile_off" not in batch:
+        raise ValueError(
+            f"aggregate_backend={cfg.aggregate_backend!r} needs the batch's "
+            f"stage-2b layout arrays (agg_*), but this batch has none; "
+            f"build them with kernels.layout.build_layer_layouts")
     use_fused = use_kernel and cfg.aggregate_backend == "pallas_fused"
 
     def _agg(kind: str) -> jax.Array:

@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import functools
 import os
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -38,9 +39,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.layout import (  # noqa: F401  (re-exported host builders)
-    BLK, EDGE_STREAM_BACKENDS, block_capacities, build_block_coo_pair,
-    build_block_csr, build_block_csr_pair, build_layer_layouts,
-    chunk_schedule, compact_layout_bytes, dense_layout_bytes,
+    BLK, EDGE_CHUNK, EDGE_STREAM_BACKENDS, block_capacities,
+    build_block_coo_pair, build_block_csr, build_block_csr_pair,
+    build_layer_layouts, compact_layout_bytes, dense_layout_bytes,
     densified_tile_bytes, densify_tiles_np, edge_stream_layout_bytes)
 from repro.kernels.update_mlp import update_epilogue
 
@@ -241,22 +242,38 @@ aggregate_compact_vjp.defvjp(_agg_compact_fwd, _agg_compact_bwd)
 # device HBM (``densify_tiles``) before the SpMM — the dense footprint the
 # compact layout was built to avoid merely moved from PCIe to HBM. The
 # paper's scatter-gather PEs stream edges and accumulate per-destination in
-# on-chip BRAM (HitGNN §3, Eq. 2/6); this kernel is that datapath on the
+# on-chip BRAM (HitGNN §3, Eq. 2/6); these kernels are that datapath on the
 # TPU memory hierarchy: the layout builder re-sorts the per-edge triples
 # into per-tile contiguous segments (CSR-style ``tile_seg`` offsets over
-# the tile slots), and each grid step densifies ITS 128x128 adjacency tile
-# in VMEM — streaming the segment in fixed-size chunks, turning each chunk
+# the tile slots), and each grid step (i, k) densifies tile (i, k) in VMEM
+# — streaming its segment in EDGE_CHUNK-edge windows, turning each window
 # into a (rows-one-hot * val)^T @ cols-one-hot MXU outer product — right
 # before the tile's matmul. No (Nd, max_blk, 128, 128) tensor ever exists
 # in HBM, forward or backward.
+#
+# Memory placement (what Mosaic accepts at the paper's layer-0 shapes,
+# where a whole (n_dstb, max_blk) ``cols`` table or ``tile_seg`` overflows
+# SMEM and a whole (1, E) edge stream overflows scoped VMEM):
+#   * SMEM holds only the CURRENT dst row's scalar tables — its ``cols``
+#     row and its tiles' segment bounds (``seg_lo``/``seg_hi``), blocked by
+#     the row index;
+#   * the source features stay in HBM (``memory_space=ANY``): each tile's
+#     (BLK, F) source block is DMA'd by the kernel, keyed by ``cols``, into
+#     a two-slot VMEM buffer, so tile k+1's fetch overlaps tile k's densify;
+#   * the edge stream stays in HBM and is DMA'd in EDGE_CHUNK-edge windows
+#     that start on a multiple of EDGE_CHUNK (E is padded to one); the
+#     validity mask drops a window's edges outside the tile's segment;
+#   * padded tile slots (empty segments) fetch and multiply nothing.
+# Interpret mode runs the same windows and DMA schedule; only the
+# per-window densify differs (a scatter-add for the MXU contraction — equal
+# bit for bit under the sampler's distinct-pair contract, see
+# ``_densify_scatter``).
 
-EDGE_CHUNK = 128  # edges densified per MXU outer-product step
+def _densify_scatter(a_tile, off, v, valid):
+    """Interpret-mode window densify: scatter the window's edges into the
+    tile.
 
-
-def _densify_scatter(a_tile, off, v, start, end, c, chunk, base):
-    """Interpret-mode chunk densify: scatter the window's edges into the tile.
-
-    ``off`` IS the flat cell offset inside the BLK x BLK tile, so the chunk
+    ``off`` IS the flat cell offset inside the BLK x BLK tile, so the window
     densifies as a 1D scatter-add — O(chunk) work instead of the
     chunk x BLK x BLK one-hot contraction the MXU path uses.  Bitwise-equal
     to that contraction whenever tile cells are single-edge (the sampler's
@@ -264,52 +281,190 @@ def _densify_scatter(a_tile, off, v, start, end, c, chunk, base):
     only ever adds +0.0 terms, which are fp32 addition identities for every
     value the cell can hold (a -0.0 edge value lands as +0.0 on the
     0.0-initialised cell under both formulations)."""
-    idx = base + jax.lax.broadcasted_iota(jnp.int32, (chunk,), 0)
-    valid = (idx >= start + c * chunk) & (idx < end)
-    tgt = jnp.where(valid, off.reshape(chunk), BLK * BLK)
-    contrib = jnp.where(valid, v.reshape(chunk), 0.0)
+    n = off.shape[0]
+    tgt = jnp.where(valid, off, BLK * BLK).reshape(n)
+    contrib = jnp.where(valid, v, 0.0).reshape(n)
     return a_tile.reshape(-1).at[tgt].add(
         contrib, mode="drop").reshape(BLK, BLK)
 
 
-def _edges_kernel(cols_ref, seg_ref, off_ref, val_ref, h_ref, o_ref,
-                  acc_ref, *, n_blk: int, chunk: int, n_edges: int,
-                  interpret: bool = False):
-    del cols_ref  # consumed by the index_map (scalar prefetch)
-    i, k = pl.program_id(0), pl.program_id(2)
+def _densify_segment(start, end, off_hbm, val_hbm, win, *,
+                     interpret: bool) -> jax.Array:
+    """Densify the edge segment ``[start, end)`` into a (BLK, BLK) fp32
+    tile.
+
+    The segment streams from HBM through the two-slot VMEM scratch ``win``
+    in EDGE_CHUNK-edge windows. The first window starts at ``start``
+    rounded down to a multiple of EDGE_CHUNK (Mosaic slices the lane axis
+    only at tile boundaries), and the validity mask drops the neighbouring
+    segments' edges a window overlaps. The DMA for window c+1 is issued
+    BEFORE the wait on window c, so the copy engine fills one slot while
+    the MXU consumes the other (the double-buffer timeline in
+    ARCHITECTURE.md). An empty segment issues no window."""
+    obuf, vbuf, osem, vsem = win
+    chunk = EDGE_CHUNK
+    first = start // chunk * chunk
+    n_win = jnp.where(end > start, (end - first + chunk - 1) // chunk, 0)
+
+    def copies(c):
+        slot = jax.lax.rem(c, 2)
+        base = pl.multiple_of(first + c * chunk, chunk)
+        return (pltpu.make_async_copy(off_hbm.at[0, pl.ds(base, chunk)],
+                                      obuf.at[slot], osem.at[slot]),
+                pltpu.make_async_copy(val_hbm.at[0, pl.ds(base, chunk)],
+                                      vbuf.at[slot], vsem.at[slot]))
+
+    @pl.when(n_win > 0)
+    def _first_window():
+        for cp in copies(0):
+            cp.start()
+
+    def window(c, a_tile):
+        @pl.when(c + 1 < n_win)
+        def _next_window():
+            for cp in copies(c + 1):
+                cp.start()
+
+        for cp in copies(c):
+            cp.wait()
+        slot = jax.lax.rem(c, 2)
+        off = obuf[slot].reshape(chunk, 1)
+        v = vbuf[slot].reshape(chunk, 1)
+        idx = (first + c * chunk
+               + jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0))
+        valid = (idx >= start) & (idx < end)
+        if interpret:
+            return _densify_scatter(a_tile, off, v, valid)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (chunk, BLK), 1)
+        rv = jnp.where((off // BLK == lane) & valid, v, 0.0)
+        cm = (off % BLK == lane).astype(jnp.float32)
+        # a_tile[r, c] += sum_e v_e [row_e == r][col_e == c]: one MXU
+        # contraction over the window axis densifies `chunk` edges at once
+        return a_tile + jax.lax.dot_general(
+            rv, cm, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    return jax.lax.fori_loop(0, n_win, window,
+                             jnp.zeros((BLK, BLK), jnp.float32))
+
+
+class _Stream(NamedTuple):
+    """The refs every streaming kernel walks a dst row with: the row's SMEM
+    tables, the HBM edge stream and source features, and the two VMEM
+    double buffers. Kernels take them as the first six inputs and the last
+    six scratch refs (``_stream_specs`` / ``_stream_scratch``)."""
+    cols: Any
+    seg_lo: Any
+    seg_hi: Any
+    off: Any
+    val: Any
+    h: Any
+    hbuf: Any
+    hsem: Any
+    obuf: Any
+    vbuf: Any
+    osem: Any
+    vsem: Any
+
+    @classmethod
+    def split(cls, refs):
+        """(stream, the kernel's own refs in between)."""
+        return cls(*refs[:6], *refs[-6:]), list(refs[6:-6])
+
+    @property
+    def win(self):
+        return self.obuf, self.vbuf, self.osem, self.vsem
+
+    def nonempty(self, k):
+        return self.seg_hi[0, k] > self.seg_lo[0, k]
+
+    def src_copy(self, k):
+        slot = jax.lax.rem(k, 2)
+        row = pl.multiple_of(self.cols[0, k] * BLK, BLK)
+        return pltpu.make_async_copy(self.h.at[pl.ds(row, BLK)],
+                                     self.hbuf.at[slot], self.hsem.at[slot])
+
+    def accumulate(self, k, n_blk: int, acc_ref, *, interpret: bool):
+        """Grid step (i, k): ``acc += A[i, k] @ h[cols[i, k]]`` for a
+        non-empty tile. Issues tile k+1's source-block DMA first (step 0
+        also issues its own), so the fetch overlaps this tile's densify;
+        an empty tile skips the fetch and the matmul, which would only add
+        +0.0 to the accumulator."""
+        @pl.when((k == 0) & self.nonempty(0))
+        def _fetch_first():
+            self.src_copy(0).start()
+
+        nxt = jnp.minimum(k + 1, n_blk - 1)
+
+        @pl.when((k + 1 < n_blk) & self.nonempty(nxt))
+        def _fetch_next():
+            self.src_copy(nxt).start()
+
+        @pl.when(self.nonempty(k))
+        def _tile():
+            a_tile = _densify_segment(self.seg_lo[0, k], self.seg_hi[0, k],
+                                      self.off, self.val, self.win,
+                                      interpret=interpret)
+            self.src_copy(k).wait()
+            acc_ref[...] += jnp.dot(a_tile, self.hbuf[jax.lax.rem(k, 2)],
+                                    preferred_element_type=jnp.float32)
+
+
+def _stream_specs(max_blk: int) -> list:
+    # (n_rows, 1, max_blk) tables: a (1, max_blk) block spans the array's
+    # last two dims, which Mosaic accepts for any max_blk
+    row = pl.BlockSpec((None, 1, max_blk), lambda i, k: (i, 0, 0),
+                       memory_space=pltpu.SMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    return [row, row, row, hbm, hbm, hbm]
+
+
+def _edge_stream_hbm(tile_off, val) -> list:
+    """The (1, E) HBM edge stream, padded to a multiple of EDGE_CHUNK with
+    edges past ``seg[-1]`` (never valid) — a no-op for the layouts
+    ``build_layer_layouts`` emits, whose capacity is already rounded."""
+    pad = -tile_off.shape[0] % EDGE_CHUNK
+    return [jnp.pad(tile_off.astype(jnp.int32), (0, pad)).reshape(1, -1),
+            jnp.pad(val.astype(jnp.float32), (0, pad)).reshape(1, -1)]
+
+
+def _stream_operands(tile_off, val, seg, cols, h) -> list:
+    """Row tables (``seg`` split into per-tile lo/hi bounds shaped like
+    ``cols``) + the HBM edge stream and source features."""
+    n, m = cols.shape
+    return [cols.reshape(n, 1, m), seg[:-1].reshape(n, 1, m),
+            seg[1:].reshape(n, 1, m), *_edge_stream_hbm(tile_off, val), h]
+
+
+def _stream_scratch(h) -> list:
+    return [pltpu.VMEM((2, BLK, h.shape[1]), h.dtype),   # src blocks
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((2, EDGE_CHUNK), jnp.int32),      # edge windows
+            pltpu.VMEM((2, EDGE_CHUNK), jnp.float32),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((2,))]
+
+
+def _pad_lanes(x: jax.Array, axis: int) -> jax.Array:
+    """Zero-pad ``axis`` up to a multiple of BLK (MXU lane alignment)."""
+    n = x.shape[axis]
+    pad = -n % BLK
+    if not pad:
+        return x
+    widths = [(0, 0)] * x.ndim
+    widths[axis] = (0, pad)
+    return jnp.pad(x, widths)
+
+
+def _edges_kernel(*refs, n_blk: int, interpret: bool):
+    st, (o_ref, acc_ref) = _Stream.split(refs)
+    k = pl.program_id(1)
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    t = i * n_blk + k
-    start = seg_ref[0, t]
-    end = seg_ref[0, t + 1]
-    n_chunks = (end - start + chunk - 1) // chunk
-    lane = jax.lax.broadcasted_iota(jnp.int32, (chunk, BLK), 1)
-
-    def densify_chunk(c, a_tile):
-        # clamp the window into bounds; validity below re-masks the overlap
-        base = jnp.minimum(start + c * chunk, n_edges - chunk)
-        off = off_ref[0, pl.ds(base, chunk)].reshape(chunk, 1)
-        v = val_ref[0, pl.ds(base, chunk)].reshape(chunk, 1)
-        if interpret:
-            return _densify_scatter(a_tile, off, v, start, end, c, chunk,
-                                    base)
-        idx = base + jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0)
-        valid = (idx >= start + c * chunk) & (idx < end)
-        rv = jnp.where((off // BLK == lane) & valid, v, 0.0)
-        cm = (off % BLK == lane).astype(jnp.float32)
-        # a_tile[r, c] += sum_e v_e [row_e == r][col_e == c]: one MXU
-        # contraction over the chunk axis densifies `chunk` edges at once
-        return a_tile + jax.lax.dot_general(
-            rv, cm, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    a_tile = jax.lax.fori_loop(0, n_chunks, densify_chunk,
-                               jnp.zeros((BLK, BLK), jnp.float32))
-    acc_ref[...] += jnp.dot(a_tile, h_ref[...],
-                            preferred_element_type=jnp.float32)
+    st.accumulate(k, n_blk, acc_ref, interpret=interpret)
 
     @pl.when(k == n_blk - 1)
     def _done():
@@ -318,7 +473,6 @@ def _edges_kernel(cols_ref, seg_ref, off_ref, val_ref, h_ref, o_ref,
 
 def aggregate_edges(tile_off: jax.Array, val: jax.Array, seg: jax.Array,
                     cols: jax.Array, h_in: jax.Array, *,
-                    feat_block: int = 256, edge_chunk: int = EDGE_CHUNK,
                     interpret: bool = True) -> jax.Array:
     """out = A @ h_in with A streamed from per-tile edge segments.
 
@@ -326,45 +480,31 @@ def aggregate_edges(tile_off: jax.Array, val: jax.Array, seg: jax.Array,
     val (E,) f32 matching edge values; seg (n_dstb * max_blk + 1,) i32
     CSR-style segment offsets over the tile slots (masked/padded edges live
     past seg[-1] and are never read as valid); cols (n_dstb, max_blk) i32
-    scalar-prefetch source-block table; h_in (n_src_pad, F).
+    source-block table; h_in (n_src_pad, F).
     Returns (n_dstb * BLK, F).
 
-    Grid and accumulator discipline match ``aggregate_blockcsr`` exactly
-    (same (i, j, k) order, same fp32 VMEM accumulator, same per-tile
-    ``jnp.dot``), and a VMEM-densified tile is bit-identical to its
-    scatter-added twin whenever tile cells are single-edge (the sampler's
-    distinct-pair contract) — so the two backends train bit-identically
-    per seed in interpret mode."""
+    Accumulator discipline matches ``aggregate_blockcsr`` (same tile order
+    per dst row, same fp32 VMEM accumulator, same per-tile ``jnp.dot``),
+    and a VMEM-densified tile is bit-identical to its scatter-added twin
+    whenever tile cells are single-edge (the sampler's distinct-pair
+    contract) — so the two backends train bit-identically per seed in
+    interpret mode. The compiled path lane-pads F to a multiple of 128."""
     n_dstb, max_blk = cols.shape
-    n_src_pad, F = h_in.shape
-    E = tile_off.shape[0]
-    if E == 0:  # zero-capacity layer: A is empty, the product is zero
+    F = h_in.shape[1]
+    if tile_off.shape[0] == 0:  # zero-capacity layer: A is empty
         return jnp.zeros((n_dstb * BLK, F), h_in.dtype)
-    h_in, F_pad, fb = _pad_feature_dim(h_in, feat_block)
-    chunk = min(edge_chunk, E)
-    grid = (n_dstb, F_pad // fb, max_blk)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            # seg + the edge stream stay whole (the same VMEM block every
-            # step — Pallas re-uses it); segments are sliced dynamically
-            pl.BlockSpec((1, seg.shape[0]), lambda i, j, k, cols: (0, 0)),
-            pl.BlockSpec((1, E), lambda i, j, k, cols: (0, 0)),
-            pl.BlockSpec((1, E), lambda i, j, k, cols: (0, 0)),
-            pl.BlockSpec((BLK, fb), lambda i, j, k, cols: (cols[i, k], j)),
-        ],
-        out_specs=pl.BlockSpec((BLK, fb), lambda i, j, k, cols: (i, j)),
-        scratch_shapes=[pltpu.VMEM((BLK, fb), jnp.float32)],
-    )
+    h_k = h_in if interpret else _pad_lanes(h_in, 1)
+    F_pad = h_k.shape[1]
     out = pl.pallas_call(
-        functools.partial(_edges_kernel, n_blk=max_blk, chunk=chunk,
-                          n_edges=E, interpret=interpret),
-        grid_spec=grid_spec,
+        functools.partial(_edges_kernel, n_blk=max_blk, interpret=interpret),
+        grid=(n_dstb, max_blk),
+        in_specs=_stream_specs(max_blk),
+        out_specs=pl.BlockSpec((BLK, F_pad), lambda i, k: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_dstb * BLK, F_pad), h_in.dtype),
+        scratch_shapes=[pltpu.VMEM((BLK, F_pad), jnp.float32),
+                        *_stream_scratch(h_k)],
         interpret=interpret,
-    )(cols, seg.reshape(1, -1), tile_off.reshape(1, E).astype(jnp.int32),
-      val.reshape(1, E).astype(jnp.float32), h_in)
+    )(*_stream_operands(tile_off, val, seg, cols, h_k))
     return out[:, :F] if F_pad != F else out
 
 
@@ -375,30 +515,30 @@ def aggregate_edges(tile_off: jax.Array, val: jax.Array, seg: jax.Array,
 # a zero/float0 cotangent, and no dense tile tensor exists in HBM in
 # either direction.
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(9,))
 def aggregate_edges_vjp(tile_off: jax.Array, val: jax.Array,
                         seg: jax.Array, cols: jax.Array,
                         tile_off_t: jax.Array, val_t: jax.Array,
                         seg_t: jax.Array, cols_t: jax.Array,
-                        h_in: jax.Array, feat_block: int = 256,
-                        interpret: bool = True) -> jax.Array:
+                        h_in: jax.Array, interpret: bool = True
+                        ) -> jax.Array:
     """Differentiable ``A @ h_in`` with A in edge-streaming segment form."""
     return aggregate_edges(tile_off, val, seg, cols, h_in,
-                           feat_block=feat_block, interpret=interpret)
+                           interpret=interpret)
 
 
 def _agg_edges_fwd(tile_off, val, seg, cols, tile_off_t, val_t, seg_t,
-                   cols_t, h_in, feat_block, interpret):
+                   cols_t, h_in, interpret):
     out = aggregate_edges_vjp(tile_off, val, seg, cols, tile_off_t, val_t,
-                              seg_t, cols_t, h_in, feat_block, interpret)
+                              seg_t, cols_t, h_in, interpret)
     return out, (tile_off, val, seg, cols, tile_off_t, val_t, seg_t, cols_t)
 
 
-def _agg_edges_bwd(feat_block, interpret, res, g):
+def _agg_edges_bwd(interpret, res, g):
     tile_off, val, seg, cols, tile_off_t, val_t, seg_t, cols_t = res
     # cast back to the primal dtype (g carries the out dtype == h_in.dtype)
     dh = aggregate_edges(tile_off_t, val_t, seg_t, cols_t,
-                         g.astype(jnp.float32), feat_block=feat_block,
+                         g.astype(jnp.float32),
                          interpret=interpret).astype(g.dtype)
 
     def f0(a):
@@ -419,11 +559,8 @@ aggregate_edges_vjp.defvjp(_agg_edges_fwd, _agg_edges_bwd)
 # layer as separate dispatches: aggregate kernel -> (Nd*BLK, F) intermediate
 # in HBM -> XLA matmul against the update weights. This kernel is HitGNN's
 # full on-chip datapath (and GenGNN's single-pass message passing) on the
-# TPU memory hierarchy: each grid step (i, k) DMAs tile (i, k)'s edge
-# segment from HBM into a two-slot VMEM scratch in ``chunk``-edge windows —
-# window c+1 is prefetched while the MXU densifies window c — densifies the
-# 128x128 adjacency tile via the same outer-product contraction as
-# ``_edges_kernel``, and multiplies it against the feature block into the
+# TPU memory hierarchy: each grid step (i, k) streams and densifies tile
+# (i, k) exactly like ``_edges_kernel`` (``_Stream.accumulate``) into the
 # fp32 row-block accumulator. On the FINAL k-step of each output row-block
 # the update MLP runs right there with its weights resident in VMEM
 # (``update_mlp.update_epilogue`` — the shared update-stage tail), so the
@@ -434,7 +571,7 @@ aggregate_edges_vjp.defvjp(_agg_edges_fwd, _agg_edges_bwd)
 # in XLA, whose reduce strategy is M-dependent and therefore NOT
 # bitwise-reproducible from padded shapes — the fused layer term is
 # bit-identical in interpret mode to ``pallas_edges`` + the XLA matmul:
-# the aggregation reuses the exact grid order and fp32 accumulator, and XLA
+# the aggregation reuses the exact tile order and fp32 accumulator, and XLA
 # CPU matmuls are row/column-independent and zero-padding-neutral (measured
 # properties; see ARCHITECTURE.md "fused stage-2c datapath"). The backward
 # ``dw`` contraction accumulates one partial per 128-row dst block, which
@@ -442,107 +579,20 @@ aggregate_edges_vjp.defvjp(_agg_edges_fwd, _agg_edges_bwd)
 # row block (zero-padded rows are bitwise-neutral); multi-block dst layers
 # get allclose, not bitwise, ``dw``.
 
-def _pad_lanes(x: jax.Array, axis: int) -> jax.Array:
-    """Zero-pad ``axis`` up to a multiple of BLK (MXU lane alignment)."""
-    n = x.shape[axis]
-    pad = -n % BLK
-    if not pad:
-        return x
-    widths = [(0, 0)] * x.ndim
-    widths[axis] = (0, pad)
-    return jnp.pad(x, widths)
-
-
-def _stream_densify_tile(seg_ref, off_hbm, val_hbm, obuf, vbuf, osem, vsem,
-                         *, t, chunk: int, n_edges: int,
-                         interpret: bool = False) -> jax.Array:
-    """Densify tile ``t``'s edge segment into a (BLK, BLK) fp32 tile.
-
-    The segment ``[seg[t], seg[t+1])`` streams from HBM through the two-slot
-    VMEM scratch ``(obuf, vbuf)``: the DMA for window c+1 is issued BEFORE
-    the wait on window c, so the copy engine fills one slot while the MXU
-    consumes the other (the double-buffer timeline in ARCHITECTURE.md).
-    The densify math — clamped window base, validity re-mask, one-hot
-    outer-product contraction — is the same chunk recurrence as
-    ``_edges_kernel``, so the produced tile (and everything accumulated
-    from it) is bit-identical to the edge-streaming kernel's.
-
-    Under ``interpret=True`` (the CPU path) the async-copy machinery is a
-    sequential emulation — every start/wait pair costs real work and
-    overlaps nothing — so the windows are read straight off the refs
-    instead. The window base, masking, and contraction are shared, so the
-    two paths produce identical bits; the compiled TPU path keeps the DMA
-    double buffer."""
-    start = seg_ref[0, t]
-    end = seg_ref[0, t + 1]
-    n_chunks = (end - start + chunk - 1) // chunk
-    lane = jax.lax.broadcasted_iota(jnp.int32, (chunk, BLK), 1)
-
-    def _base(c):
-        # clamp the window into bounds; validity below re-masks the overlap
-        return jnp.minimum(start + c * chunk, n_edges - chunk)
-
-    def _copy(c, ref, buf, sem):
-        slot = jax.lax.rem(c, 2)
-        return pltpu.make_async_copy(ref.at[0, pl.ds(_base(c), chunk)],
-                                     buf.at[slot], sem.at[slot])
-
-    if not interpret:
-        @pl.when(n_chunks > 0)
-        def _prefetch_first():
-            _copy(0, off_hbm, obuf, osem).start()
-            _copy(0, val_hbm, vbuf, vsem).start()
-
-    def densify_chunk(c, a_tile):
-        if interpret:
-            off = pl.load(off_hbm, (pl.ds(0, 1),
-                                    pl.ds(_base(c), chunk))).reshape(chunk, 1)
-            v = pl.load(val_hbm, (pl.ds(0, 1),
-                                  pl.ds(_base(c), chunk))).reshape(chunk, 1)
-            return _densify_scatter(a_tile, off, v, start, end, c, chunk,
-                                    _base(c))
-        else:
-            @pl.when(c + 1 < n_chunks)
-            def _prefetch_next():
-                _copy(c + 1, off_hbm, obuf, osem).start()
-                _copy(c + 1, val_hbm, vbuf, vsem).start()
-            _copy(c, off_hbm, obuf, osem).wait()
-            _copy(c, val_hbm, vbuf, vsem).wait()
-            slot = jax.lax.rem(c, 2)
-            off = obuf[slot].reshape(chunk, 1)
-            v = vbuf[slot].reshape(chunk, 1)
-        idx = _base(c) + jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0)
-        valid = (idx >= start + c * chunk) & (idx < end)
-        rv = jnp.where((off // BLK == lane) & valid, v, 0.0)
-        cm = (off % BLK == lane).astype(jnp.float32)
-        return a_tile + jax.lax.dot_general(
-            rv, cm, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    return jax.lax.fori_loop(0, n_chunks, densify_chunk,
-                             jnp.zeros((BLK, BLK), jnp.float32))
-
-
-def _fused_kernel(*refs, n_blk: int, chunk: int, n_edges: int, act: str,
-                  has_bias: bool, has_self: bool, z_dtype,
-                  interpret: bool = False):
-    (cols_ref, seg_ref, off_hbm, val_hbm, h_ref, w_ref) = refs[:6]
-    rest = list(refs[6:])
-    del cols_ref  # consumed by the index_map (scalar prefetch)
+def _fused_kernel(*refs, n_blk: int, act: str, has_bias: bool,
+                  has_self: bool, z_dtype, interpret: bool):
+    st, rest = _Stream.split(refs)
+    w_ref = rest.pop(0)
     b_ref = rest.pop(0) if has_bias else None
     s_ref = rest.pop(0) if has_self else None
-    o_ref, acc_ref, obuf, vbuf, osem, vsem = rest
-    i, k = pl.program_id(0), pl.program_id(1)
+    o_ref, acc_ref = rest
+    k = pl.program_id(1)
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    a_tile = _stream_densify_tile(seg_ref, off_hbm, val_hbm, obuf, vbuf,
-                                  osem, vsem, t=i * n_blk + k, chunk=chunk,
-                                  n_edges=n_edges, interpret=interpret)
-    acc_ref[...] += jnp.dot(a_tile, h_ref[...],
-                            preferred_element_type=jnp.float32)
+    st.accumulate(k, n_blk, acc_ref, interpret=interpret)
 
     @pl.when(k == n_blk - 1)
     def _update():
@@ -555,31 +605,25 @@ def _fused_kernel(*refs, n_blk: int, chunk: int, n_edges: int, act: str,
         o_ref[...] = update_epilogue(y, b, act).astype(o_ref.dtype)
 
 
-def _fused_bwd_kernel(*refs, n_blk: int, chunk: int, n_edges: int,
-                      act: str, has_bias: bool, has_self: bool, z_dtype,
-                      interpret: bool = False):
-    (cols_ref, seg_ref, off_hbm, val_hbm, h_ref, g_ref) = refs[:6]
-    rest = list(refs[6:])
-    del cols_ref
+def _fused_bwd_kernel(*refs, n_blk: int, act: str, has_bias: bool,
+                      has_self: bool, z_dtype, interpret: bool):
+    st, rest = _Stream.split(refs)
+    g_ref = rest.pop(0)
     w_ref = rest.pop(0) if act != "none" else None
     b_ref = rest.pop(0) if act != "none" and has_bias else None
     s_ref = rest.pop(0) if has_self else None
     dw_ref = rest.pop(0)
     db_ref = rest.pop(0) if has_bias else None
     dy_ref = rest.pop(0) if act != "none" else None
-    acc_ref, dw_acc, obuf, vbuf, osem, vsem = rest[:6]
-    db_acc = rest[6] if has_bias else None
+    acc_ref, dw_acc = rest[:2]
+    db_acc = rest[2] if has_bias else None
     i, k = pl.program_id(0), pl.program_id(1)
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    a_tile = _stream_densify_tile(seg_ref, off_hbm, val_hbm, obuf, vbuf,
-                                  osem, vsem, t=i * n_blk + k, chunk=chunk,
-                                  n_edges=n_edges, interpret=interpret)
-    acc_ref[...] += jnp.dot(a_tile, h_ref[...],
-                            preferred_element_type=jnp.float32)
+    st.accumulate(k, n_blk, acc_ref, interpret=interpret)
 
     @pl.when(k == n_blk - 1)
     def _grads():
@@ -633,37 +677,25 @@ def _fused_bwd_kernel(*refs, n_blk: int, chunk: int, n_edges: int,
                 db_ref[...] = db_acc[...].astype(db_ref.dtype)
 
 
-def _fused_operands(tile_off, val, seg, cols, h_in, w, b, s, edge_chunk,
-                    interpret=False):
-    """Shared fwd/bwd operand prep: lane-pad the MLP operands and shape the
-    edge stream for the HBM-resident (memory_space=ANY) DMA source.
+def _fused_operands(h_in, w, b, s, interpret):
+    """Shared fwd/bwd operand prep: lane-pad the MLP operands.
 
     Lane padding exists only for Mosaic's 128-lane tiling; interpret mode
     accepts any block width, and the pad columns are all-zero (bitwise
     neutral in every contraction), so the CPU path skips them — at F=64
     that halves the per-grid-step copy and dot volume."""
-    E = tile_off.shape[0]
-    chunk = min(edge_chunk, E)
     if interpret:
-        h_k, w_k, b_k, s_k = h_in, w, b, s
-    else:
-        h_k = _pad_lanes(h_in, 1)
-        w_k = _pad_lanes(_pad_lanes(w, 0), 1)
-        b_k = _pad_lanes(b, 0) if b is not None else None
-        s_k = _pad_lanes(s, 1) if s is not None else None
-    F_pad = h_k.shape[1]
-    off2 = tile_off.reshape(1, E).astype(jnp.int32)
-    val2 = val.reshape(1, E).astype(jnp.float32)
-    seg2 = seg.reshape(1, -1)
-    return chunk, h_k, F_pad, w_k, b_k, s_k, off2, val2, seg2
+        return h_in, w, b, s
+    return (_pad_lanes(h_in, 1), _pad_lanes(_pad_lanes(w, 0), 1),
+            _pad_lanes(b, 0) if b is not None else None,
+            _pad_lanes(s, 1) if s is not None else None)
 
 
 def aggregate_fused(tile_off: jax.Array, val: jax.Array, seg: jax.Array,
                     cols: jax.Array, h_in: jax.Array, w: jax.Array,
                     b: jax.Array | None = None, s: jax.Array | None = None,
                     *, act: str = "none", z_dtype=None,
-                    edge_chunk: int = EDGE_CHUNK, interpret: bool = True
-                    ) -> jax.Array:
+                    interpret: bool = True) -> jax.Array:
     """out = act((A @ h_in [+ s]) @ w [+ b]) in ONE Pallas grid.
 
     A streams from the per-tile edge segments (``tile_off``/``val``/``seg``
@@ -679,63 +711,45 @@ def aggregate_fused(tile_off: jax.Array, val: jax.Array, seg: jax.Array,
     n_dstb, max_blk = cols.shape
     F = h_in.shape[1]
     N = w.shape[1]
-    E = tile_off.shape[0]
     if z_dtype is None:
         z_dtype = h_in.dtype
     out_dtype = jnp.result_type(z_dtype, w.dtype)
-    if E == 0:  # zero-capacity layer: mirror the unfused XLA composition
+    if tile_off.shape[0] == 0:  # zero-capacity layer: mirror the XLA path
         z = jnp.zeros((n_dstb * BLK, F), z_dtype)
         if s is not None:
             z = z + s
         return update_epilogue(jnp.dot(z, w), b, act).astype(out_dtype)
-    chunk, h_k, F_pad, w_k, b_k, s_k, off2, val2, seg2 = _fused_operands(
-        tile_off, val, seg, cols, h_in, w, b, s, edge_chunk,
-        interpret=interpret)
-    N_pad = w_k.shape[1]
+    h_k, w_k, b_k, s_k = _fused_operands(h_in, w, b, s, interpret)
+    F_pad, N_pad = w_k.shape
     has_bias, has_self = b is not None, s is not None
 
-    in_specs = [
-        pl.BlockSpec((1, seg2.shape[1]), lambda i, k, cols: (0, 0)),
-        pl.BlockSpec(memory_space=pltpu.ANY),  # tile_off: DMA'd per chunk
-        pl.BlockSpec(memory_space=pltpu.ANY),  # val: DMA'd per chunk
-        pl.BlockSpec((BLK, F_pad), lambda i, k, cols: (cols[i, k], 0)),
-        pl.BlockSpec((F_pad, N_pad), lambda i, k, cols: (0, 0)),
-    ]
-    operands = [seg2, off2, val2, h_k, w_k]
+    in_specs = _stream_specs(max_blk) + [
+        pl.BlockSpec((F_pad, N_pad), lambda i, k: (0, 0))]
+    operands = _stream_operands(tile_off, val, seg, cols, h_k) + [w_k]
     if has_bias:
-        in_specs.append(pl.BlockSpec((N_pad,), lambda i, k, cols: (0,)))
+        in_specs.append(pl.BlockSpec((N_pad,), lambda i, k: (0,)))
         operands.append(b_k)
     if has_self:
-        in_specs.append(pl.BlockSpec((BLK, F_pad), lambda i, k, cols: (i, 0)))
+        in_specs.append(pl.BlockSpec((BLK, F_pad), lambda i, k: (i, 0)))
         operands.append(s_k)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+    out = pl.pallas_call(
+        functools.partial(_fused_kernel, n_blk=max_blk, act=act,
+                          has_bias=has_bias, has_self=has_self,
+                          z_dtype=z_dtype, interpret=interpret),
         grid=(n_dstb, max_blk),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((BLK, N_pad), lambda i, k, cols: (i, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((BLK, F_pad), jnp.float32),   # row-block aggregate
-            pltpu.VMEM((2, chunk), jnp.int32),       # off double buffer
-            pltpu.VMEM((2, chunk), jnp.float32),     # val double buffer
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(_fused_kernel, n_blk=max_blk, chunk=chunk,
-                          n_edges=E, act=act, has_bias=has_bias,
-                          has_self=has_self, z_dtype=z_dtype,
-                          interpret=interpret),
-        grid_spec=grid_spec,
+        out_specs=pl.BlockSpec((BLK, N_pad), lambda i, k: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_dstb * BLK, N_pad), out_dtype),
+        scratch_shapes=[pltpu.VMEM((BLK, F_pad), jnp.float32),  # aggregate
+                        *_stream_scratch(h_k)],
         interpret=interpret,
-    )(cols, *operands)
+    )(*operands)
     return out[:, :N] if N_pad != N else out
 
 
 def _fused_bwd_call(tile_off, val, seg, cols, h_in, g, w, b, s, *, act,
-                    z_dtype, edge_chunk, interpret):
+                    z_dtype, interpret):
     """Backward recompute pass: streams the SAME A segments through the same
     grid, rebuilds each row-block aggregate (and, for activated MLPs, the
     pre-activation) in VMEM, and contracts it against the incoming cotangent.
@@ -743,71 +757,49 @@ def _fused_bwd_call(tile_off, val, seg, cols, h_in, g, w, b, s, *, act,
     n_dstb, max_blk = cols.shape
     F = h_in.shape[1]
     N = w.shape[1]
-    E = tile_off.shape[0]
-    chunk, h_k, F_pad, w_k, b_k, s_k, off2, val2, seg2 = _fused_operands(
-        tile_off, val, seg, cols, h_in, w, b, s, edge_chunk,
-        interpret=interpret)
-    N_pad = w_k.shape[1]
+    h_k, w_k, b_k, s_k = _fused_operands(h_in, w, b, s, interpret)
+    F_pad, N_pad = w_k.shape
     has_bias, has_self = b is not None, s is not None
     g_k = g if interpret else _pad_lanes(g, 1)
 
-    in_specs = [
-        pl.BlockSpec((1, seg2.shape[1]), lambda i, k, cols: (0, 0)),
-        pl.BlockSpec(memory_space=pltpu.ANY),
-        pl.BlockSpec(memory_space=pltpu.ANY),
-        pl.BlockSpec((BLK, F_pad), lambda i, k, cols: (cols[i, k], 0)),
-        pl.BlockSpec((BLK, N_pad), lambda i, k, cols: (i, 0)),
-    ]
-    operands = [seg2, off2, val2, h_k, g_k]
+    in_specs = _stream_specs(max_blk) + [
+        pl.BlockSpec((BLK, N_pad), lambda i, k: (i, 0))]
+    operands = _stream_operands(tile_off, val, seg, cols, h_k) + [g_k]
     if act != "none":
-        in_specs.append(pl.BlockSpec((F_pad, N_pad),
-                                     lambda i, k, cols: (0, 0)))
+        in_specs.append(pl.BlockSpec((F_pad, N_pad), lambda i, k: (0, 0)))
         operands.append(w_k)
         if has_bias:
-            in_specs.append(pl.BlockSpec((N_pad,), lambda i, k, cols: (0,)))
+            in_specs.append(pl.BlockSpec((N_pad,), lambda i, k: (0,)))
             operands.append(b_k)
     if has_self:
-        in_specs.append(pl.BlockSpec((BLK, F_pad), lambda i, k, cols: (i, 0)))
+        in_specs.append(pl.BlockSpec((BLK, F_pad), lambda i, k: (i, 0)))
         operands.append(s_k)
 
-    out_specs = [pl.BlockSpec((F_pad, N_pad), lambda i, k, cols: (0, 0))]
+    out_specs = [pl.BlockSpec((F_pad, N_pad), lambda i, k: (0, 0))]
     out_shapes = [jax.ShapeDtypeStruct((F_pad, N_pad), jnp.float32)]
     if has_bias:
-        out_specs.append(pl.BlockSpec((1, N_pad), lambda i, k, cols: (0, 0)))
+        out_specs.append(pl.BlockSpec((1, N_pad), lambda i, k: (0, 0)))
         out_shapes.append(jax.ShapeDtypeStruct((1, N_pad), jnp.float32))
     if act != "none":
-        out_specs.append(pl.BlockSpec((BLK, N_pad),
-                                      lambda i, k, cols: (i, 0)))
+        out_specs.append(pl.BlockSpec((BLK, N_pad), lambda i, k: (i, 0)))
         out_shapes.append(jax.ShapeDtypeStruct((n_dstb * BLK, N_pad),
                                                g.dtype))
 
-    scratch = [
-        pltpu.VMEM((BLK, F_pad), jnp.float32),
-        pltpu.VMEM((F_pad, N_pad), jnp.float32),
-        pltpu.VMEM((2, chunk), jnp.int32),
-        pltpu.VMEM((2, chunk), jnp.float32),
-        pltpu.SemaphoreType.DMA((2,)),
-        pltpu.SemaphoreType.DMA((2,)),
-    ]
+    scratch = [pltpu.VMEM((BLK, F_pad), jnp.float32),
+               pltpu.VMEM((F_pad, N_pad), jnp.float32)]
     if has_bias:
         scratch.append(pltpu.VMEM((1, N_pad), jnp.float32))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+    outs = pl.pallas_call(
+        functools.partial(_fused_bwd_kernel, n_blk=max_blk, act=act,
+                          has_bias=has_bias, has_self=has_self,
+                          z_dtype=z_dtype, interpret=interpret),
         grid=(n_dstb, max_blk),
         in_specs=in_specs,
         out_specs=out_specs,
-        scratch_shapes=scratch,
-    )
-    outs = pl.pallas_call(
-        functools.partial(_fused_bwd_kernel, n_blk=max_blk, chunk=chunk,
-                          n_edges=E, act=act, has_bias=has_bias,
-                          has_self=has_self, z_dtype=z_dtype,
-                          interpret=interpret),
-        grid_spec=grid_spec,
         out_shape=out_shapes,
+        scratch_shapes=scratch + _stream_scratch(h_k),
         interpret=interpret,
-    )(cols, *operands)
+    )(*operands)
     outs = list(outs)
     dw = outs.pop(0)[:F, :N]
     db = outs.pop(0)[0, :N] if has_bias else None
@@ -815,10 +807,9 @@ def _fused_bwd_call(tile_off, val, seg, cols, h_in, g, w, b, s, *, act,
     return dw, db, dy
 
 
-def _fused_bwd_merged_kernel(*refs, n_blk: int, n_blk_t: int, chunk: int,
-                             chunk_t: int, n_edges: int, n_edges_t: int,
+def _fused_bwd_merged_kernel(*refs, n_blk: int, n_blk_t: int,
                              has_bias: bool, has_self: bool, z_dtype,
-                             interpret: bool = False):
+                             interpret: bool):
     """Single-dst-block backward: dw recompute AND dh in ONE grid pass.
 
     With one destination row block (``n_dstb == 1``, the bitwise-pinned
@@ -829,42 +820,41 @@ def _fused_bwd_merged_kernel(*refs, n_blk: int, n_blk_t: int, chunk: int,
     the edge-streaming kernel's recurrence verbatim (same TRANSPOSED
     segments, same 0-initialised accumulate over all ``n_blk_t`` slots of
     the block's transposed row), so its bits match ``aggregate_edges`` for
-    any edge multiplicity.  Padded ``cols`` slots re-derive the same block
-    from the same transposed segments — duplicate writes are idempotent.
-    Source blocks no tile touches are masked to +0.0 by the caller
-    (exactly the reference's zero-segment output)."""
-    (cols_ref, seg_ref, off_hbm, val_hbm, seg_t_ref, offt_hbm, valt_hbm,
-     h_ref, g_ref, dz_ref) = refs[:10]
-    rest = list(refs[10:])
+    any edge multiplicity; it is DMA'd to its rows of the HBM output.
+    Source blocks no tile touches are never written and are masked to +0.0
+    by the caller (exactly the reference's zero-segment output)."""
+    st, rest = _Stream.split(refs)
+    seg_t_ref, offt_hbm, valt_hbm, g_ref, dz_ref = rest[:5]
+    rest = rest[5:]
     s_ref = rest.pop(0) if has_self else None
     dw_ref = rest.pop(0)
     db_ref = rest.pop(0) if has_bias else None
-    dh_ref = rest.pop(0)
-    (acc_ref, obuf, vbuf, osem, vsem,
-     obuf2, vbuf2, osem2, vsem2) = rest
-    i, k = pl.program_id(0), pl.program_id(1)
+    dh_hbm, acc_ref, dh_buf, dh_sem = rest
+    k = pl.program_id(1)
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    a_tile = _stream_densify_tile(seg_ref, off_hbm, val_hbm, obuf, vbuf,
-                                  osem, vsem, t=i * n_blk + k, chunk=chunk,
-                                  n_edges=n_edges, interpret=interpret)
-    acc_ref[...] += jnp.dot(a_tile, h_ref[...],
-                            preferred_element_type=jnp.float32)
+    st.accumulate(k, n_blk, acc_ref, interpret=interpret)
 
-    src_blk = cols_ref[i, k]
-    dh_acc = jnp.zeros_like(dh_ref[...])
-    for k2 in range(n_blk_t):
-        at_tile = _stream_densify_tile(seg_t_ref, offt_hbm, valt_hbm,
-                                       obuf2, vbuf2, osem2, vsem2,
-                                       t=src_blk * n_blk_t + k2,
-                                       chunk=chunk_t, n_edges=n_edges_t,
+    @pl.when(st.nonempty(k))
+    def _emit_dh():
+        src_blk = st.cols[0, k]
+        dh_acc = jnp.zeros(dh_buf.shape, jnp.float32)
+        for k2 in range(n_blk_t):
+            t = src_blk * n_blk_t + k2
+            at_tile = _densify_segment(seg_t_ref[0, t], seg_t_ref[0, t + 1],
+                                       offt_hbm, valt_hbm, st.win,
                                        interpret=interpret)
-        dh_acc = dh_acc + jnp.dot(at_tile, dz_ref[...],
-                                  preferred_element_type=jnp.float32)
-    dh_ref[...] = dh_acc.astype(dh_ref.dtype)
+            dh_acc = dh_acc + jnp.dot(at_tile, dz_ref[...],
+                                      preferred_element_type=jnp.float32)
+        dh_buf[...] = dh_acc
+        row = pl.multiple_of(src_blk * BLK, BLK)
+        cp = pltpu.make_async_copy(dh_buf, dh_hbm.at[pl.ds(row, BLK)],
+                                   dh_sem)
+        cp.start()
+        cp.wait()
 
     @pl.when(k == n_blk - 1)
     def _grads():
@@ -881,92 +871,69 @@ def _fused_bwd_merged_kernel(*refs, n_blk: int, n_blk_t: int, chunk: int,
 
 def _fused_bwd_merged_call(tile_off, val, seg, cols, tile_off_t, val_t,
                            seg_t, cols_t, h_in, g, dz32, w, b, s, *,
-                           z_dtype, edge_chunk, interpret):
+                           z_dtype, interpret):
     """Single-pass backward for the ``n_dstb == 1`` / ``act == "none"``
     case: one grid computes dw (z recompute off the FORWARD segments) and
     dh (the TRANSPOSED segments' edge-streaming recurrence, inlined per
     source block).  Returns (dw (F, N), db (N,) | None, dh (n_src, F))."""
     n_dstb, max_blk = cols.shape
-    max_blk_t = cols_t.shape[1]
+    n_srcb, max_blk_t = cols_t.shape
     F = h_in.shape[1]
     N = w.shape[1]
-    E = tile_off.shape[0]
-    E_t = tile_off_t.shape[0]
-    chunk, h_k, F_pad, w_k, b_k, s_k, off2, val2, seg2 = _fused_operands(
-        tile_off, val, seg, cols, h_in, w, b, s, edge_chunk,
-        interpret=interpret)
-    N_pad = w_k.shape[1]
+    h_k, w_k, b_k, s_k = _fused_operands(h_in, w, b, s, interpret)
+    F_pad, N_pad = w_k.shape
     has_bias, has_self = b is not None, s is not None
     g_k = g if interpret else _pad_lanes(g, 1)
     dz_k = dz32 if interpret else _pad_lanes(dz32, 1)
-    chunk_t = min(edge_chunk, E_t)
-    off2_t = tile_off_t.reshape(1, E_t).astype(jnp.int32)
-    val2_t = val_t.reshape(1, E_t).astype(jnp.float32)
-    seg2_t = seg_t.reshape(1, -1)
-    n_srcb = h_k.shape[0] // BLK
+    # one dst row: the whole transposed offsets table is n_srcb + 1 words
+    seg_t2 = seg_t.reshape(1, -1)
 
-    in_specs = [
-        pl.BlockSpec((1, seg2.shape[1]), lambda i, k, cols: (0, 0)),
-        pl.BlockSpec(memory_space=pltpu.ANY),  # fwd tile_off
-        pl.BlockSpec(memory_space=pltpu.ANY),  # fwd val
-        pl.BlockSpec((1, seg2_t.shape[1]), lambda i, k, cols: (0, 0)),
-        pl.BlockSpec(memory_space=pltpu.ANY),  # transposed tile_off
-        pl.BlockSpec(memory_space=pltpu.ANY),  # transposed val
-        pl.BlockSpec((BLK, F_pad), lambda i, k, cols: (cols[i, k], 0)),
-        pl.BlockSpec((BLK, N_pad), lambda i, k, cols: (i, 0)),
-        pl.BlockSpec((BLK, F_pad), lambda i, k, cols: (i, 0)),
+    in_specs = _stream_specs(max_blk) + [
+        pl.BlockSpec(seg_t2.shape, lambda i, k: (0, 0),
+                     memory_space=pltpu.SMEM),
+        pl.BlockSpec(memory_space=pl.ANY),  # transposed tile_off
+        pl.BlockSpec(memory_space=pl.ANY),  # transposed val
+        pl.BlockSpec((BLK, N_pad), lambda i, k: (i, 0)),
+        pl.BlockSpec((BLK, F_pad), lambda i, k: (i, 0)),
     ]
-    operands = [seg2, off2, val2, seg2_t, off2_t, val2_t, h_k, g_k, dz_k]
+    operands = _stream_operands(tile_off, val, seg, cols, h_k) + [
+        seg_t2, *_edge_stream_hbm(tile_off_t, val_t), g_k, dz_k]
     if has_self:
-        in_specs.append(pl.BlockSpec((BLK, F_pad), lambda i, k, cols: (i, 0)))
+        in_specs.append(pl.BlockSpec((BLK, F_pad), lambda i, k: (i, 0)))
         operands.append(s_k)
 
-    out_specs = [pl.BlockSpec((F_pad, N_pad), lambda i, k, cols: (0, 0))]
+    out_specs = [pl.BlockSpec((F_pad, N_pad), lambda i, k: (0, 0))]
     out_shapes = [jax.ShapeDtypeStruct((F_pad, N_pad), jnp.float32)]
     if has_bias:
-        out_specs.append(pl.BlockSpec((1, N_pad), lambda i, k, cols: (0, 0)))
+        out_specs.append(pl.BlockSpec((1, N_pad), lambda i, k: (0, 0)))
         out_shapes.append(jax.ShapeDtypeStruct((1, N_pad), jnp.float32))
-    out_specs.append(pl.BlockSpec((BLK, F_pad),
-                                  lambda i, k, cols: (cols[i, k], 0)))
+    out_specs.append(pl.BlockSpec(memory_space=pl.ANY))  # dh, DMA'd
     out_shapes.append(jax.ShapeDtypeStruct((n_srcb * BLK, F_pad),
                                            jnp.float32))
 
-    scratch = [
-        pltpu.VMEM((BLK, F_pad), jnp.float32),
-        pltpu.VMEM((2, chunk), jnp.int32),
-        pltpu.VMEM((2, chunk), jnp.float32),
-        pltpu.SemaphoreType.DMA((2,)),
-        pltpu.SemaphoreType.DMA((2,)),
-        pltpu.VMEM((2, chunk_t), jnp.int32),
-        pltpu.VMEM((2, chunk_t), jnp.float32),
-        pltpu.SemaphoreType.DMA((2,)),
-        pltpu.SemaphoreType.DMA((2,)),
-    ]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+    outs = pl.pallas_call(
+        functools.partial(_fused_bwd_merged_kernel, n_blk=max_blk,
+                          n_blk_t=max_blk_t, has_bias=has_bias,
+                          has_self=has_self, z_dtype=z_dtype,
+                          interpret=interpret),
         grid=(n_dstb, max_blk),
         in_specs=in_specs,
         out_specs=out_specs,
-        scratch_shapes=scratch,
-    )
-    outs = pl.pallas_call(
-        functools.partial(_fused_bwd_merged_kernel, n_blk=max_blk,
-                          n_blk_t=max_blk_t, chunk=chunk, chunk_t=chunk_t,
-                          n_edges=E, n_edges_t=E_t, has_bias=has_bias,
-                          has_self=has_self, z_dtype=z_dtype,
-                          interpret=interpret),
-        grid_spec=grid_spec,
         out_shape=out_shapes,
+        scratch_shapes=[pltpu.VMEM((BLK, F_pad), jnp.float32),  # aggregate
+                        pltpu.VMEM((BLK, F_pad), jnp.float32),  # dh block
+                        pltpu.SemaphoreType.DMA(()),
+                        *_stream_scratch(h_k)],
         interpret=interpret,
-    )(cols, *operands)
+    )(*operands)
     outs = list(outs)
     dw = outs.pop(0)[:F, :N]
     db = outs.pop(0)[0, :N] if has_bias else None
     dh_raw = outs.pop(0)[:, :F]
-    # untouched source blocks never get a grid-step write; the reference's
-    # zero-segment recurrence leaves them at exactly +0.0
-    covered = jnp.zeros((n_srcb,), bool).at[cols[0]].set(True, mode="drop")
+    # source blocks of no non-empty tile never get a dh write; the
+    # reference's zero-segment recurrence leaves them at exactly +0.0
+    touched = jnp.where(seg[1:] > seg[:-1], cols[0], n_srcb)
+    covered = jnp.zeros((n_srcb,), bool).at[touched].set(True, mode="drop")
     dh = jnp.where(jnp.repeat(covered, BLK)[:, None], dh_raw, 0.0)
     return dw, db, dh
 
@@ -984,35 +951,31 @@ def _fused_bwd_merged_call(tile_off, val, seg, cols, tile_off_t, val_t,
 #   dw = sum_i z_i^T dy_i      (in-kernel recompute of z, per-row-block)
 #   db = sum_rows dy           (in-kernel, only when the bias is fused)
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(12, 13, 14, 15, 16, 17))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(12, 13, 14, 15, 16))
 def aggregate_fused_vjp(tile_off: jax.Array, val: jax.Array, seg: jax.Array,
                         cols: jax.Array, tile_off_t: jax.Array,
                         val_t: jax.Array, seg_t: jax.Array,
                         cols_t: jax.Array, h_in: jax.Array, w: jax.Array,
                         b: jax.Array, s: jax.Array, act: str = "none",
                         has_bias: bool = False, has_self: bool = False,
-                        z_dtype=None, edge_chunk: int = EDGE_CHUNK,
-                        interpret: bool = True) -> jax.Array:
+                        z_dtype=None, interpret: bool = True) -> jax.Array:
     """Differentiable ``act((A @ h [+ s]) @ w [+ b])``, A in segment form."""
     return aggregate_fused(tile_off, val, seg, cols, h_in, w,
                            b if has_bias else None,
                            s if has_self else None, act=act,
-                           z_dtype=z_dtype, edge_chunk=edge_chunk,
-                           interpret=interpret)
+                           z_dtype=z_dtype, interpret=interpret)
 
 
 def _fused_fwd(tile_off, val, seg, cols, tile_off_t, val_t, seg_t, cols_t,
-               h_in, w, b, s, act, has_bias, has_self, z_dtype, edge_chunk,
-               interpret):
+               h_in, w, b, s, act, has_bias, has_self, z_dtype, interpret):
     out = aggregate_fused_vjp(tile_off, val, seg, cols, tile_off_t, val_t,
                               seg_t, cols_t, h_in, w, b, s, act, has_bias,
-                              has_self, z_dtype, edge_chunk, interpret)
+                              has_self, z_dtype, interpret)
     return out, (tile_off, val, seg, cols, tile_off_t, val_t, seg_t, cols_t,
                  h_in, w, b, s)
 
 
-def _fused_bwd(act, has_bias, has_self, z_dtype, edge_chunk, interpret,
-               res, g):
+def _fused_bwd(act, has_bias, has_self, z_dtype, interpret, res, g):
     (tile_off, val, seg, cols, tile_off_t, val_t, seg_t, cols_t,
      h_in, w, b, s) = res
     zd = h_in.dtype if z_dtype is None else z_dtype
@@ -1031,8 +994,7 @@ def _fused_bwd(act, has_bias, has_self, z_dtype, edge_chunk, interpret,
         _, pullback = jax.vjp(_f, w, b, s)
         dw, db, ds = pullback(g)
         dh = jnp.zeros_like(h_in)
-    elif (n_dstb == 1 and act == "none" and F <= 256
-          and tile_off_t.shape[0] > 0):
+    elif n_dstb == 1 and act == "none" and F <= 256:
         # single-dst-block fast path: dw recompute and dh share ONE grid
         # (see _fused_bwd_merged_kernel) — bits identical to the two-pass
         # composition below
@@ -1041,7 +1003,7 @@ def _fused_bwd(act, has_bias, has_self, z_dtype, edge_chunk, interpret,
             tile_off, val, seg, cols, tile_off_t, val_t, seg_t, cols_t,
             h_in, g, dz.astype(jnp.float32), w,
             b if has_bias else None, s if has_self else None,
-            z_dtype=zd, edge_chunk=edge_chunk, interpret=interpret)
+            z_dtype=zd, interpret=interpret)
         dh = dh.astype(h_in.dtype)
         dw = dw.astype(w.dtype)
         db = db.astype(b.dtype) if has_bias else jnp.zeros_like(b)
@@ -1050,12 +1012,12 @@ def _fused_bwd(act, has_bias, has_self, z_dtype, edge_chunk, interpret,
         dw, db, dy = _fused_bwd_call(
             tile_off, val, seg, cols, h_in, g, w,
             b if has_bias else None, s if has_self else None,
-            act=act, z_dtype=zd, edge_chunk=edge_chunk, interpret=interpret)
+            act=act, z_dtype=zd, interpret=interpret)
         if act == "none":
             dy = g
         dz = jax.lax.dot_general(dy, w, (((1,), (1,)), ((), ())))
         dh = aggregate_edges(tile_off_t, val_t, seg_t, cols_t,
-                             dz.astype(jnp.float32), edge_chunk=edge_chunk,
+                             dz.astype(jnp.float32),
                              interpret=interpret).astype(h_in.dtype)
         dw = dw.astype(w.dtype)
         db = db.astype(b.dtype) if has_bias else jnp.zeros_like(b)

@@ -31,6 +31,10 @@ from typing import List, Optional, Tuple
 import numpy as np
 
 BLK = 128
+# the streaming kernels DMA the edge stream in windows of this many edges,
+# starting on multiples of it (Mosaic slices lanes at tile boundaries), so
+# every per-layer edge capacity is rounded up to a multiple of it
+EDGE_CHUNK = 128
 
 # aggregate_backend values that consume the per-tile SEGMENT layout (the
 # ``edge_stream=True`` builder output) instead of the scatter-densify
@@ -241,25 +245,6 @@ def _edge_stream_sort(coo: dict, mask: np.ndarray, n_tiles: int,
     return sorted_fields
 
 
-def chunk_schedule(tile_seg: np.ndarray, edge_chunk: int
-                   ) -> Tuple[np.ndarray, int]:
-    """Per-tile DMA chunk counts for the fused kernel's double buffer.
-
-    The fused aggregation kernel streams tile ``t``'s segment
-    ``[tile_seg[t], tile_seg[t+1])`` from HBM into a two-slot VMEM scratch
-    in ``edge_chunk``-edge windows, prefetching window ``c+1`` while the MXU
-    densifies window ``c``. This host-side twin of that schedule returns
-    ``(counts, max_chunks)``: ``counts[t]`` is the number of DMA windows
-    tile ``t`` issues (``ceil(seg_len / edge_chunk)``) and ``max_chunks``
-    the worst tile — the simulator prices the fused datapath from it
-    (``core/simulator.py``) and the bench reports it, while the kernel
-    itself walks the same counts dynamically from ``tile_seg`` in VMEM."""
-    seg = np.asarray(tile_seg, np.int64)
-    lens = seg[1:] - seg[:-1]
-    counts = ((lens + edge_chunk - 1) // edge_chunk).astype(np.int32)
-    return counts, int(counts.max()) if len(counts) else 0
-
-
 def compact_layout_bytes(n_edges: int, n_dstb: int, max_blk: int,
                          n_srcb: int, max_blk_t: int) -> int:
     """Host->device bytes per batch for one layer's compact layout: three
@@ -318,7 +303,9 @@ def block_capacities(cfg) -> List[Tuple[int, int, int, int, int]]:
     Returns one ``(n_src, n_dst, max_blk, max_blk_t, e_cap)`` tuple per
     layer. A dst block holds <= BLK * fanout edges, so it can touch at most
     that many distinct src blocks; the transpose has no fanout bound on its
-    rows (a source may feed arbitrarily many destinations). One shape per
+    rows (a source may feed arbitrarily many destinations). ``e_cap`` is
+    the layer's edge capacity rounded up to a multiple of EDGE_CHUNK;
+    ``build_layer_layouts`` pads the edge lists to it. One shape per
     config => one compiled executable across the epoch."""
     from repro.core.sampler import layer_capacities  # local: no jax either
     n_caps, e_caps = layer_capacities(cfg)
@@ -330,7 +317,7 @@ def block_capacities(cfg) -> List[Tuple[int, int, int, int, int]]:
         max_blk = min(n_srcb, BLK * fans[l])
         max_blk_t = n_dstb
         caps.append((n_caps[l], n_caps[l + 1], max_blk, max_blk_t,
-                     e_caps[l]))
+                     -(-e_caps[l] // EDGE_CHUNK) * EDGE_CHUNK))
     return caps
 
 
@@ -370,8 +357,10 @@ def build_layer_layouts(edge_src: List[np.ndarray],
     consumes (``aggregate_backend="pallas_edges"``)."""
     keys = EDGE_STREAM_KEYS if edge_stream else LAYOUT_KEYS
     out: dict = {f"agg_{k}": [] for k in keys}
-    for l, (n_src, n_dst, max_blk, max_blk_t, _) in enumerate(caps):
-        src, dst, mask = edge_src[l], edge_dst[l], edge_mask[l]
+    for l, (n_src, n_dst, max_blk, max_blk_t, e_cap) in enumerate(caps):
+        pad = (0, e_cap - len(edge_src[l]))  # masked tail up to e_cap
+        src, dst = np.pad(edge_src[l], pad), np.pad(edge_dst[l], pad)
+        mask = np.pad(edge_mask[l], pad)
         vals = None
         if kind == "mean":
             deg = np.bincount(dst[mask], minlength=n_dst)

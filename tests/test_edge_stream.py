@@ -440,3 +440,26 @@ def test_unknown_backend_rejected():
     with pytest.raises(ValueError, match="aggregate_backend"):
         SyncGNNTrainer(G, CFG, num_devices=1,
                        aggregate_backend="pallas_vmem")
+
+
+@pytest.mark.parametrize("backend", ["pallas", "pallas_edges",
+                                     "pallas_fused"])
+def test_kernel_backend_without_layout_raises(backend):
+    """A kernel backend never quietly runs the reference aggregation: a
+    batch without the stage-2b layout arrays is an error. GAT has no kernel
+    path and always runs the reference edge list."""
+    from repro.core.trainer import batch_to_arrays
+    from repro.nn.param import materialize
+    mb = NeighborSampler(G, CFG, G.train_ids, 0, seed=0).batch_at(0, 0)
+    batch = batch_to_arrays(mb, G.features[np.asarray(mb.nodes[0])])
+    for name, ok in (("graphsage", False), ("gat", True)):
+        cfg = GNNModelConfig(name, num_layers=2, hidden=16, fanouts=(4, 3),
+                             batch_targets=32, aggregate_backend=backend)
+        params = materialize(gnn_models.param_spec(
+            cfg, G.features.shape[1], G.num_classes), jax.random.PRNGKey(0))
+        if ok:
+            assert np.isfinite(np.asarray(
+                gnn_models.forward(cfg, params, batch))).all()
+        else:
+            with pytest.raises(ValueError, match="layout"):
+                gnn_models.forward(cfg, params, batch)

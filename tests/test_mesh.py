@@ -42,6 +42,14 @@ class TestDeviceValidation:
             SyncGNNTrainer(G, CFG, num_devices=jax.device_count() + 1,
                            data_parallel=True)
 
+    def test_too_many_devices_on_accelerator_has_no_cpu_hint(
+            self, monkeypatch):
+        # simulated CPU devices cannot stand in for missing chips
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        with pytest.raises(ValueError, match="tpu device") as err:
+            make_data_mesh(jax.device_count() + 1)
+        assert "xla_force_host_platform" not in str(err.value)
+
     def test_mesh_axis_extent_mismatch_raises(self):
         mesh = make_data_mesh(1)
         with pytest.raises(ValueError, match="does not match"):

@@ -306,3 +306,18 @@ def test_trainer_validates_knobs():
         SyncGNNTrainer(G, CFG, num_devices=2, balance_policy="fastest")
     with pytest.raises(ValueError, match="num_sampler_workers"):
         SyncGNNTrainer(G, CFG, num_devices=2, num_sampler_workers=-1)
+
+
+def test_worker_modules_never_import_jax():
+    """A spawned sampler worker imports only these modules: were one of
+    them to pull in jax, every worker of a pool feeding an accelerator
+    could try to claim the chip the training process already holds."""
+    import os
+    import subprocess
+    import sys
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    code = ("import sys, repro.core.sampler_pool; "
+            "sys.exit('jax' in sys.modules)")
+    env = dict(os.environ, PYTHONPATH=src)
+    assert subprocess.run([sys.executable, "-c", code], env=env,
+                          timeout=120).returncode == 0

@@ -203,6 +203,26 @@ def test_runtime_predict_matches_ground_truth_forward():
     assert (got == want).all()
 
 
+@pytest.mark.parametrize("backend", ["pallas_edges", "pallas_fused"])
+def test_runtime_kernel_backend_matches_reference(backend):
+    """A kernel backend serves through its own Pallas path (bucket-shaped
+    layouts built per micro-batch), equal to the reference backend's
+    logits on the same request stream."""
+    params = _params()
+    ids = [np.asarray(G.train_ids[:m], np.int32) for m in (3, 11)]
+    with ServingRuntime(G, CFG, params,
+                        serve_cfg=ServeConfig(num_workers=0)) as rt:
+        want = [rt.predict(i) for i in ids]
+    cfg = GNNModelConfig("graphsage", num_layers=2, hidden=8,
+                         fanouts=(3, 2), batch_targets=16,
+                         aggregate_backend=backend)
+    with ServingRuntime(G, cfg, params,
+                        serve_cfg=ServeConfig(num_workers=0)) as rt:
+        got = [rt.predict(i) for i in ids]
+    for w, g in zip(want, got):
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-5)
+
+
 def test_runtime_pool_path_bitwise_equals_in_process():
     params = _params()
     ids_a = np.asarray(G.train_ids[:5], np.int32)

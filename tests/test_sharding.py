@@ -10,12 +10,9 @@ from repro.distributed import sharding as shd
 
 
 def _make_mesh():
-    # 1 CPU device: (1,1) mesh exercises the code paths. AxisType only
-    # exists on newer jax; explicit Auto matches the old default anyway.
-    kw = {}
-    if hasattr(jax.sharding, "AxisType"):
-        kw["axis_types"] = (jax.sharding.AxisType.Auto,) * 2
-    return jax.make_mesh((1, 1), ("data", "model"), **kw)
+    # 1 CPU device: (1,1) mesh exercises the code paths
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 @pytest.fixture(scope="module")
