@@ -1,0 +1,14 @@
+"""Milliseconds per batch of the sampling service's kernel layout build
+(``kernels/layout.build_layer_layouts``), summed over its workers: the
+``layout_s`` arguments of the window's ``feed/stages`` spans, plus
+``feed/layout`` spans where the stage ran in the training process, over
+the window's batches. Worker busy time, not a share of the wall clock."""
+from bench import feed_trace
+
+
+def read(ctx):
+    ft, rec = feed_trace.load(ctx), ctx["record"]
+    if ft is None or not rec.get("batches") or not (
+            ft.named("feed/stages") or ft.named("feed/layout")):
+        return None
+    return 1e3 * ft.stage_seconds("layout") / rec["batches"]

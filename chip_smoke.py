@@ -22,11 +22,14 @@ one chip over the same four partitions. The losses must agree within
 MESH_RTOL, the feature shard must sit on four distinct devices, and the
 compiled mesh step must hold the collectives.
 
-Every phase prints one line. The script exits non-zero, printing no
-result, when a phase fails, a loss is not finite, or JAX finds no TPU;
-otherwise its last line is ``{"ok": true, "device": {...}}``. The
-compile cache follows ``repro.compile_cache``, so a second run compiles
-less.
+Every phase prints one line; a train line carries the epoch's backend
+``compiles`` and the trainer's set-up seconds by phase
+(``setup_phase_s``), and the last phase the process's ``compile_s`` and
+``cache_hits`` (``repro.compile_cache.compile_counter``). The script
+exits non-zero, printing no result, when a phase fails, a loss is not
+finite, or JAX finds no TPU; otherwise its last line is ``{"ok": true,
+"device": {...}}``. The compile cache follows ``repro.compile_cache``, so
+a second run compiles less.
 """
 import argparse
 import json
@@ -70,26 +73,6 @@ def tpu_device() -> dict:
             "count": len(devices)}
 
 
-class CompileClock:
-    """Seconds JAX spends in backend compiles (persistent-cache lookups
-    included) and the persistent-cache hits, from JAX's own events."""
-
-    def __init__(self):
-        import jax
-        self.seconds = 0.0
-        self.cache_hits = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, duration, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += duration
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-
 def model_cfg(backend: str):
     from repro.configs.gnn import GNNModelConfig
     return GNNModelConfig("graphsage", num_layers=2, hidden=128,
@@ -118,6 +101,8 @@ def train_epoch(graph, backend, platform, algorithm, clock, workers,
             "acc": m["acc"], "steps": res.trainer.step_no,
             "batches": m["batches"], "sampler_workers": workers,
             "compile_s": compile_s, "wall_s": wall,
+            "compiles": m["compiles"],
+            "setup_phase_s": res.trainer.setup_phase_s,
             "tpu_custom_call": text.count("tpu_custom_call"),
             "all_reduce": text.count("all-reduce("),
             "all_to_all": text.count("all-to-all(")}
@@ -202,7 +187,7 @@ def main() -> int:
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
     args = ap.parse_args()
     try:
-        from repro.compile_cache import enable_compile_cache
+        from repro.compile_cache import compile_counter, enable_compile_cache
         from repro.data.graphs import scaled_dataset
     except ImportError as e:
         print(f"chip_smoke: FAIL: the repro package is not next to this "
@@ -213,7 +198,7 @@ def main() -> int:
         check(device["count"] >= args.chips,
               f"--chips {args.chips} but JAX sees {device['count']}")
         cache = enable_compile_cache()
-        clock = CompileClock()
+        clock = compile_counter()
         t0 = time.perf_counter()
         graph = scaled_dataset(DATASET, scale=SCALE)
         print(json.dumps({"phase": "setup", "device": device,

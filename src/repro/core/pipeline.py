@@ -33,17 +33,86 @@ WHERE the iteration items come from is no longer this module's concern:
 serving request queues both feed the same ``SchedulingCore``), and this
 executor overlaps whatever payload stream that seam yields with device
 compute. See also ``core/serving.py`` for the request-driven frontend.
+
+Each counter of the feed is kept by :class:`timed`, which opens the
+profiler span of the same name over the same interval: in a
+``jax.profiler`` trace the spans sit on the host plane of the file that
+holds the device's operations, on the same clock, so an idle gap on the
+device is named by the feed stage it waited on.
 """
 from __future__ import annotations
 
+import itertools
 import queue
 import threading
 import time
 import traceback
 from dataclasses import dataclass
-from typing import Any, Callable, Iterable, Iterator, Optional
+from typing import Any, Callable, Iterable, Iterator, Optional, Union
 
 _SENTINEL = object()
+_open = threading.local()  # the innermost timed block, per thread
+_annotation = None
+
+
+def trace_annotation():
+    """``jax.profiler.TraceAnnotation``, imported on first use: sampler
+    worker processes import this module but never jax."""
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation
+
+
+class timed:
+    """Time a block into a counter and open the profiler span ``name``
+    over the same interval, so the counter and its span always agree.
+
+    ``counters`` is a :class:`PipelineStats` (``key`` an attribute) or a
+    dict (``key`` an item). ``self_time=True`` leaves the time of timed
+    blocks nested inside this one, on the same thread, out of its counter
+    (the span still covers them). ``args`` (the iteration number, say)
+    are attached to the span only while a trace is active.
+
+    Sampler worker processes never use it: they hold no profiler and time
+    their stages with ``time.perf_counter``."""
+
+    __slots__ = ("name", "counters", "key", "self_time", "args", "_span",
+                 "_outer", "_inner", "_t0")
+
+    def __init__(self, name: str, counters: Union["PipelineStats", dict],
+                 key: str, self_time: bool = False, **args):
+        self.name, self.counters, self.key = name, counters, key
+        self.self_time, self.args = self_time, args
+
+    def __enter__(self) -> "timed":
+        span = trace_annotation()
+        if self.args and span.is_enabled():
+            self._span = span(self.name, **{
+                k: v for k, v in self.args.items() if v is not None})
+        else:
+            self._span = span(self.name)
+        self._span.__enter__()
+        self._outer = getattr(_open, "block", None)
+        _open.block = self
+        self._inner = 0.0
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        dt = time.perf_counter() - self._t0
+        self._span.__exit__(*exc)
+        _open.block = self._outer
+        if self._outer is not None:
+            self._outer._inner += dt
+        if self.self_time:
+            dt -= self._inner
+        if isinstance(self.counters, dict):
+            self.counters[self.key] = self.counters.get(self.key, 0.0) + dt
+        else:
+            setattr(self.counters, self.key,
+                    getattr(self.counters, self.key) + dt)
 
 
 @dataclass
@@ -51,17 +120,29 @@ class PipelineStats:
     """Per-epoch timing split: host produce time vs consumer queue-wait.
 
     ``produce_s`` is the wall time the worker spent inside ``prepare`` (the
-    sample+gather stages); ``wait_s`` is how long the consumer blocked on an
-    empty queue (host-bound iterations); overlap quality is visible as
-    wait_s << produce_s. ``gather_s`` isolates the stage-2 share of
-    ``produce_s`` — the feature gather (in-process) or placement tail
-    (worker-gathered rows) — and ``ring_bytes`` counts the payload bytes
-    that crossed the sampling service's shared-memory ring, so the stage-2
-    offload's effect on the training thread is measurable per epoch."""
+    sample+gather stages; span ``feed/assemble``); ``wait_s`` is how long
+    the consumer blocked on an empty queue (host-bound iterations); overlap
+    quality is visible as wait_s << produce_s. ``source_wait_s`` is the
+    worker's own wait for its next item (span ``feed/pool_wait``: the
+    sampling service's fetch, less the ring decode it runs), and
+    ``dispatch_s`` the consumer's time handing each step to the device
+    (span ``step/dispatch``). ``sample_s`` and ``layout_s`` are the
+    sampling stages when they run in this process (spans ``feed/sample``
+    and ``feed/layout``, inside ``feed/assemble``); with a sampling
+    service they are the pool's.
+    ``gather_s`` isolates the stage-2 share of ``produce_s`` — the feature
+    gather (in-process) or placement tail (worker-gathered rows) — and
+    ``ring_bytes`` counts the payload bytes that crossed the sampling
+    service's shared-memory ring, so the stage-2 offload's effect on the
+    training thread is measurable per epoch."""
 
     items: int = 0
     produce_s: float = 0.0
     wait_s: float = 0.0
+    source_wait_s: float = 0.0
+    dispatch_s: float = 0.0
+    sample_s: float = 0.0
+    layout_s: float = 0.0
     gather_s: float = 0.0
     ring_bytes: int = 0
 
@@ -70,16 +151,19 @@ class PrefetchExecutor:
     """Bounded-queue producer/consumer executor for one epoch.
 
     ``run(items)`` yields ``prepare(item)`` results in order while the
-    worker thread stays up to ``depth`` items ahead.
+    worker thread stays up to ``depth`` items ahead. The worker's spans
+    carry ``iteration``: ``first_iteration`` plus the item's index.
     """
 
     def __init__(self, prepare: Callable[[Any], Any], depth: int = 2,
-                 stats: Optional[PipelineStats] = None):
+                 stats: Optional[PipelineStats] = None,
+                 first_iteration: int = 0):
         if depth < 1:
             raise ValueError("prefetch depth must be >= 1")
         self.prepare = prepare
         self.depth = depth
         self.stats = stats if stats is not None else PipelineStats()
+        self.first_iteration = first_iteration
 
     def run(self, items: Iterable[Any]) -> Iterator[Any]:
         q: queue.Queue = queue.Queue(maxsize=self.depth)
@@ -90,11 +174,17 @@ class PrefetchExecutor:
         error: list[tuple[BaseException, str]] = []
 
         def worker() -> None:
+            stats, source = self.stats, iter(items)
             try:
-                for it in items:
-                    t0 = time.perf_counter()
-                    out = self.prepare(it)
-                    self.stats.produce_s += time.perf_counter() - t0
+                for k in itertools.count(self.first_iteration):
+                    with timed("feed/pool_wait", stats, "source_wait_s",
+                               self_time=True, iteration=k):
+                        it = next(source, _SENTINEL)
+                    if it is _SENTINEL:
+                        break
+                    with timed("feed/assemble", stats, "produce_s",
+                               iteration=k):
+                        out = self.prepare(it)
                     while not stop.is_set():
                         try:
                             q.put(out, timeout=0.05)

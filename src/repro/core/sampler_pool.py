@@ -72,6 +72,14 @@ classes on demand.
 The pool is a context manager; shared segments — graph, ring, and
 residency — are closed AND unlinked on every exit path, including error
 paths and KeyboardInterrupt.
+
+``stats`` also keeps the feed's time: each worker times its task's stages
+(``sample_s``: ``batch_at``/``request_batch``; ``layout_s``:
+``build_layer_layouts``; ``ship_s``: ``select_ship_rows`` plus the ring
+encode) with ``time.perf_counter`` and sends the seconds back with its
+result, which the supervisor adds only for the copy it delivers, so a
+speculative duplicate is not counted twice. ``decode_s`` is the consuming
+thread's ring decode and CRC check (span ``feed/decode``).
 """
 from __future__ import annotations
 
@@ -91,7 +99,7 @@ import numpy as np
 
 from repro.configs.gnn import GNNModelConfig
 from repro.core.faults import FaultInjector, FaultSpec, resolve_fault_spec
-from repro.core.pipeline import ReorderBuffer
+from repro.core.pipeline import ReorderBuffer, timed, trace_annotation
 from repro.core.residency import ResidencyCore, SharedResidency
 from repro.core.sampler import (MiniBatch, NeighborSampler, layer_capacities,
                                 pad_minibatch)
@@ -490,6 +498,7 @@ def _worker_main(worker_id: int, spec: SharedGraphSpec, cfg: GNNModelConfig,
                         inject = "encode_overflow"
                     elif injector.fire("corrupt_slot", tid) is not None:
                         inject = "corrupt_slot"
+                t0 = time.perf_counter()
                 if targets is None:
                     mb = samplers[part].batch_at(epoch, index)
                 else:
@@ -499,6 +508,7 @@ def _worker_main(worker_id: int, spec: SharedGraphSpec, cfg: GNNModelConfig,
                     mb = pad_minibatch(
                         samplers[part].request_batch(epoch, index, targets),
                         *layer_capacities(cfg))
+                t1 = time.perf_counter()
                 layout = None
                 if blk_caps is not None:
                     layout = build_layer_layouts(
@@ -506,6 +516,8 @@ def _worker_main(worker_id: int, spec: SharedGraphSpec, cfg: GNNModelConfig,
                         agg_kind,
                         edge_stream=(cfg.aggregate_backend
                                      in EDGE_STREAM_BACKENDS))
+                t2 = time.perf_counter()
+                ship_s = 0.0
                 feats = None
                 if residency is not None:
                     # generation handshake: the task names the cache
@@ -521,9 +533,11 @@ def _worker_main(worker_id: int, spec: SharedGraphSpec, cfg: GNNModelConfig,
                             raise GenerationStallError(str(e)) from None
                     # stage 2 in the worker: gather only what must cross
                     # the bus to `device` (all valid rows for P3 all-to-all)
+                    t3 = time.perf_counter()
                     feats = residency.select_ship_rows(
                         device, graph.features, mb.nodes[0], mb.node_mask[0],
                         p3_full=feat_spec.p3_full)
+                    ship_s = time.perf_counter() - t3
                 # acquire a ring slot only once the batch is ready: a worker
                 # never sits on a slot while it computes. The lease stamp
                 # (this worker's id) is what lets the supervisor reclaim
@@ -532,8 +546,10 @@ def _worker_main(worker_id: int, spec: SharedGraphSpec, cfg: GNNModelConfig,
                 slot = free_q.get()
                 lease[slot] = worker_id
                 try:
+                    t3 = time.perf_counter()
                     codec.encode(mb, layout, feats, ring.buf,
                                  slot * codec.nbytes, inject=inject)
+                    ship_s += time.perf_counter() - t3
                 except BaseException:
                     # the consumer will never see this slot — recycle it
                     # here or every encode failure (e.g. feature-capacity
@@ -543,7 +559,8 @@ def _worker_main(worker_id: int, spec: SharedGraphSpec, cfg: GNNModelConfig,
                     raise
                 result_q.put((seq, "ok",
                               (slot, part, index, device,
-                               mb.work_estimate())))
+                               mb.work_estimate(),
+                               (t1 - t0, t2 - t1, ship_s))))
             except BaseException as e:  # surfaced at the consumer's fetch()
                 result_q.put((seq, "error",
                               (_picklable_exc(e), traceback.format_exc())))
@@ -680,7 +697,9 @@ class SamplerPool:
                       "duplicates_dropped": 0, "stale_results": 0,
                       "retried_errors": 0,
                       "crc_failures": 0, "degraded_tasks": 0,
-                      "gen_stalls": 0, "recovery_s": 0.0}
+                      "gen_stalls": 0, "recovery_s": 0.0,
+                      "sample_s": 0.0, "layout_s": 0.0, "ship_s": 0.0,
+                      "decode_s": 0.0}
         # seq -> ([remaining duplicate causes], registered_at): filled when
         # a task with extra live copies delivers, consumed as the losers
         # land, purged by _supervise if a loser died with its worker
@@ -850,10 +869,11 @@ class SamplerPool:
                 return
             self._retry_or_surface(seq, rec, payload, "retried_errors")
             return
-        slot, part, index, device, load = payload
+        slot, part, index, device, load, stage_s = payload
         try:
-            mb, layout, feats, used = self._codec.decode(
-                self._ring.buf, slot * self._codec.nbytes, part, index)
+            with timed("feed/decode", self.stats, "decode_s"):
+                mb, layout, feats, used = self._codec.decode(
+                    self._ring.buf, slot * self._codec.nbytes, part, index)
         except RingCorruptionError as e:
             # detected corruption = transient fault: recycle the slot and
             # re-execute rather than train on garbage
@@ -870,9 +890,24 @@ class SamplerPool:
             feats["device"] = device
         self._expect_duplicates(seq, rec)
         del self._inflight[seq]
+        self._count_stages(*stage_s)
         self._rob.put(seq, ("ok", {"minibatch": mb, "layout": layout,
                                    "features": feats, "ring_bytes": used,
                                    "load": load}))
+
+    def _count_stages(self, sample_s: float, layout_s: float,
+                      ship_s: float) -> None:
+        """Add a delivered task's worker stage seconds to ``stats`` and,
+        while a trace is active, to the trace as the zero-length span
+        ``feed/stages`` with the seconds as its arguments."""
+        self.stats["sample_s"] += sample_s
+        self.stats["layout_s"] += layout_s
+        self.stats["ship_s"] += ship_s
+        span = trace_annotation()
+        if span.is_enabled():
+            with span("feed/stages", sample_s=sample_s, layout_s=layout_s,
+                      ship_s=ship_s):
+                pass
 
     def _expect_duplicates(self, seq: int, rec: _TaskRecord) -> None:
         """On delivery, remember which extra copies of ``seq`` may still
@@ -1035,27 +1070,30 @@ class SamplerPool:
             self._local_samplers = [
                 NeighborSampler(self._graph, self._cfg, ids, p, self._seed)
                 for p, ids in enumerate(self._ids)]
-        if targets is None:
-            mb = self._local_samplers[part].batch_at(epoch, index)
-        else:
-            mb = pad_minibatch(
-                self._local_samplers[part].request_batch(epoch, index,
-                                                         targets),
-                *layer_capacities(self._cfg))
+        with timed("feed/sample", self.stats, "sample_s"):
+            if targets is None:
+                mb = self._local_samplers[part].batch_at(epoch, index)
+            else:
+                mb = pad_minibatch(
+                    self._local_samplers[part].request_batch(epoch, index,
+                                                             targets),
+                    *layer_capacities(self._cfg))
         layout = None
         if self._blk_caps is not None:
-            layout = build_layer_layouts(
-                mb.edge_src, mb.edge_dst, mb.edge_mask, self._blk_caps,
-                self._agg_kind,
-                edge_stream=(self._cfg.aggregate_backend
-                             in EDGE_STREAM_BACKENDS))
+            with timed("feed/layout", self.stats, "layout_s"):
+                layout = build_layer_layouts(
+                    mb.edge_src, mb.edge_dst, mb.edge_mask, self._blk_caps,
+                    self._agg_kind,
+                    edge_stream=(self._cfg.aggregate_backend
+                                 in EDGE_STREAM_BACKENDS))
         feats = None
         if self._residency is not None:
             if gen != self._residency.generation:
                 self._residency.wait_generation(gen)
-            pos, rows = self._residency.select_ship_rows(
-                device, self._graph.features, mb.nodes[0], mb.node_mask[0],
-                p3_full=self.feat_spec.p3_full)
+            with timed("feed/ship", self.stats, "ship_s"):
+                pos, rows = self._residency.select_ship_rows(
+                    device, self._graph.features, mb.nodes[0],
+                    mb.node_mask[0], p3_full=self.feat_spec.p3_full)
             feats = {"pos": pos, "rows": rows, "device": device}
         return {"minibatch": mb, "layout": layout, "features": feats,
                 "ring_bytes": 0, "load": mb.work_estimate()}

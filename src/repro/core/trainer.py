@@ -32,6 +32,7 @@ scheduler state. Optional int8+error-feedback gradient compression
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import threading
 import time
@@ -43,12 +44,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import compile_counter
 from repro.configs.gnn import GNNModelConfig
 from repro.data.graphs import Graph
 from repro.core.partition import Partition, get_partitioner
 from repro.core.feature_cache import FeatureCache
 from repro.core.feature_store import FeatureStore
-from repro.core.pipeline import PipelineStats, PrefetchExecutor
+from repro.core.pipeline import PipelineStats, PrefetchExecutor, timed
 from repro.core.sampler import (NeighborSampler, MiniBatch,
                                 layer_capacities)
 from repro.core.sampler_pool import SamplerPool, suggest_ship_rows_cap
@@ -227,10 +229,20 @@ class SyncGNNTrainer:
         if (self.model_cfg.ship_rows_cap is not None
                 and self.model_cfg.ship_rows_cap < 1):
             raise ValueError("ship_rows_cap must be >= 1")
+        # set-up seconds by phase: partition, store, pool_spawn and
+        # shard_upload (the first upload's host build and enqueue) as they
+        # run, and compile (the process's backend compile seconds from here
+        # to the end of the first epoch) once that epoch ends
+        self.setup_phase_s: Dict[str, float] = {}
+        self._compiles = compile_counter()
+        self._compile_s0 = self._compiles.seconds
         part_name, store_name = ALGORITHMS[self.algorithm]
-        self.partition: Partition = get_partitioner(part_name)(
-            self.graph, self.num_devices, self.seed)
-        self.store = FeatureStore(self.graph, self.partition, store_name)
+        with self._setup_phase("partition"):
+            self.partition: Partition = get_partitioner(part_name)(
+                self.graph, self.num_devices, self.seed)
+        with self._setup_phase("store"):
+            self.store = FeatureStore(self.graph, self.partition,
+                                      store_name)
         # Frequency-driven HBM feature cache over the store's residency
         # core. P3 bypasses it entirely: every row is already resident as a
         # feature-dimension slice, so there is nothing to admit or ship.
@@ -384,6 +396,16 @@ class SyncGNNTrainer:
         return total
 
     # -- setup helpers ---------------------------------------------------------
+    @contextlib.contextmanager
+    def _setup_phase(self, phase: str):
+        """Add the block's wall seconds to ``setup_phase_s[phase]``."""
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            self.setup_phase_s[phase] = (self.setup_phase_s.get(phase, 0.0)
+                                         + time.perf_counter() - t0)
+
     def _train_ids(self, i: int) -> np.ndarray:
         mask = self.partition.assignment[self.graph.train_ids] == i
         ids = self.graph.train_ids[mask]
@@ -393,10 +415,13 @@ class SyncGNNTrainer:
         """Materialize every device's resident feature block and lay it
         across the mesh with a P("data") sharding: device d's slab lands in
         (and stays in) device d's memory — the paper's HBM-resident X_i.
-        Re-run at epoch start when a feature cache changed residency."""
-        mat = self.store.build_shard_matrix()
-        self._shard = jax.device_put(
-            mat, NamedSharding(self.mesh, P("data")))
+        Re-run at epoch start when a feature cache changed residency;
+        only the first upload counts as set-up."""
+        with (self._setup_phase("shard_upload") if self._shard is None
+              else contextlib.nullcontext()):
+            mat = self.store.build_shard_matrix()
+            self._shard = jax.device_put(
+                mat, NamedSharding(self.mesh, P("data")))
 
     def _make_step(self):
         cfg = self.model_cfg
@@ -432,14 +457,17 @@ class SyncGNNTrainer:
             # one slot after another (lax.map, not vmap): a batched
             # pallas_call cannot block its HBM-resident (memory_space=ANY)
             # operands, so the Mosaic kernels compile only unbatched
-            losses, metrics, per_dev = jax.lax.map(device_val_grad, stacked)
-            loss = (losses * w).sum() / w_sum
-            grads = jax.tree.map(
-                lambda g: jnp.tensordot(w, g, axes=1) / w_sum, per_dev)
+            with jax.named_scope("step/loss_grad"):
+                losses, metrics, per_dev = jax.lax.map(device_val_grad,
+                                                       stacked)
+                loss = (losses * w).sum() / w_sum
+                grads = jax.tree.map(
+                    lambda g: jnp.tensordot(w, g, axes=1) / w_sum, per_dev)
             if use_comp:
                 payload, err = compression.compress_tree(grads, err)
                 grads = compression.decompress_tree(payload)
-            new_p, new_s, om = opt.update(grads, opt_state, params)
+            with jax.named_scope("step/optimizer"):
+                new_p, new_s, om = opt.update(grads, opt_state, params)
             out_metrics = {"loss": loss,
                            "acc": (metrics["acc"] * w).sum() / w_sum, **om}
             return new_p, new_s, err, out_metrics
@@ -462,15 +490,17 @@ class SyncGNNTrainer:
         def device_grads(params, stacked, repl, vshard):
             b = dict(jax.tree.map(lambda x: x[0], stacked))
             shard = vshard[0]
-            if p3:
-                b["feats"] = gnn_models.p3_all_to_all_feats(
-                    shard, repl["ids"], repl["valid"], feat_dim)
-            else:
-                b["feats"] = gnn_models.assemble_device_feats(shard, b)
+            with jax.named_scope("step/assemble_feats"):
+                if p3:
+                    b["feats"] = gnn_models.p3_all_to_all_feats(
+                        shard, repl["ids"], repl["valid"], feat_dim)
+                else:
+                    b["feats"] = gnn_models.assemble_device_feats(shard, b)
             w = b["weight"].astype(jnp.float32)
-            (loss, metrics), grads = jax.value_and_grad(
-                lambda q: gnn_models.loss_fn(cfg, q, b),
-                has_aux=True)(params)
+            with jax.named_scope("step/loss_grad"):
+                (loss, metrics), grads = jax.value_and_grad(
+                    lambda q: gnn_models.loss_fn(cfg, q, b),
+                    has_aux=True)(params)
             w_sum = jnp.maximum(jax.lax.psum(w, "data"), 1.0)
             grads = jax.tree.map(
                 lambda g: jax.lax.psum(g * w, "data") / w_sum, grads)
@@ -488,7 +518,8 @@ class SyncGNNTrainer:
             if use_comp:
                 payload, err = compression.compress_tree(grads, err)
                 grads = compression.decompress_tree(payload)
-            new_p, new_s, om = opt.update(grads, opt_state, params)
+            with jax.named_scope("step/optimizer"):
+                new_p, new_s, om = opt.update(grads, opt_state, params)
             return new_p, new_s, err, {"loss": loss, "acc": acc, **om}
 
         return step
@@ -528,8 +559,12 @@ class SyncGNNTrainer:
         visits each partition's batches in index order, while keeping the
         checkpointable cursor advancing exactly as before the scheduling-
         core extraction — plus stage 2b (compact layout build)."""
-        mb = self.samplers[task.partition].next_batch()
-        layout = self._block_csr_arrays(mb) if self._blk_caps else None
+        with timed("feed/sample", self._pstats, "sample_s"):
+            mb = self.samplers[task.partition].next_batch()
+        layout = None
+        if self._blk_caps:
+            with timed("feed/layout", self._pstats, "layout_s"):
+                layout = self._block_csr_arrays(mb)
         return {"minibatch": mb, "layout": layout,
                 "load": mb.work_estimate()}
 
@@ -701,10 +736,10 @@ class SyncGNNTrainer:
                 mb = payload["minibatch"]
                 self.cache.observe(mb.nodes[0], mb.node_mask[0])
             self.cache.end_iteration(self._iter_no)
+        out = {"stacked": stack_batches(batches), "vertices": vertices,
+               "n_batches": len(assignments), "iteration": self._iter_no}
         self._iter_no += 1
         self._epoch_iter += 1
-        out = {"stacked": stack_batches(batches), "vertices": vertices,
-               "n_batches": len(assignments)}
         if mesh_active and self.algorithm == "p3":
             # replicated all_to_all operands: EVERY device needs every
             # batch's layer-0 ids/masks to serve its feature-dim slice
@@ -739,31 +774,36 @@ class SyncGNNTrainer:
         host never idles waiting on a result it only reads at epoch end,
         which is the second half of the Eq. 5-6 overlap (the prefetch
         thread being the first). Outstanding steps are bounded by the
-        prefetch queue depth."""
-        stacked = prepared["stacked"]
-        if self._err is None and self.grad_compression:
-            self._err = jax.tree.map(
-                lambda p: jnp.zeros_like(p, jnp.float32), self.params)
-        if self.mesh is not None:
-            # slot d of every stacked leaf lands on mesh device d; the P3
-            # all_to_all operands replicate. The feature shard was uploaded
-            # once (epoch start) and stays in device HBM across iterations.
-            data = NamedSharding(self.mesh, P("data"))
-            repl = NamedSharding(self.mesh, P())
-            stacked = jax.tree.map(
-                lambda x: jax.device_put(x, data), stacked)
-            repl_ops = jax.tree.map(lambda x: jax.device_put(x, repl),
-                                    prepared.get("repl", {}))
-            if self._shard is None:
-                self._upload_shards()
-            args = (self.params, self.opt_state, stacked, repl_ops,
-                    self._shard, self._err)
-        else:
-            args = (self.params, self.opt_state, stacked, self._err)
-        if self._step_specs is None:  # one shape per config
-            self._step_specs = jax.tree.map(_arg_spec, args)
-        (self.params, self.opt_state, self._err,
-         metrics) = self._jit_step(*args)
+        prefetch queue depth. The dispatch, up to the return of the jitted
+        step, is timed into ``PipelineStats.dispatch_s`` (span
+        ``step/dispatch``)."""
+        with timed("step/dispatch", self._pstats, "dispatch_s",
+                   iteration=prepared.get("iteration")):
+            stacked = prepared["stacked"]
+            if self._err is None and self.grad_compression:
+                self._err = jax.tree.map(
+                    lambda p: jnp.zeros_like(p, jnp.float32), self.params)
+            if self.mesh is not None:
+                # slot d of every stacked leaf lands on mesh device d; the
+                # P3 all_to_all operands replicate. The feature shard was
+                # uploaded once (epoch start) and stays in device HBM
+                # across iterations.
+                data = NamedSharding(self.mesh, P("data"))
+                repl = NamedSharding(self.mesh, P())
+                stacked = jax.tree.map(
+                    lambda x: jax.device_put(x, data), stacked)
+                repl_ops = jax.tree.map(lambda x: jax.device_put(x, repl),
+                                        prepared.get("repl", {}))
+                if self._shard is None:
+                    self._upload_shards()
+                args = (self.params, self.opt_state, stacked, repl_ops,
+                        self._shard, self._err)
+            else:
+                args = (self.params, self.opt_state, stacked, self._err)
+            if self._step_specs is None:  # one shape per config
+                self._step_specs = jax.tree.map(_arg_spec, args)
+            (self.params, self.opt_state, self._err,
+             metrics) = self._jit_step(*args)
         self.step_no += 1
         if not sync:
             return metrics
@@ -790,21 +830,22 @@ class SyncGNNTrainer:
         if self._pool is None:
             kind = (gnn_models.AGG_KIND[self.model_cfg.name]
                     if self._blk_caps else None)
-            self._pool = SamplerPool(
-                self.graph, self.model_cfg,
-                [self._train_ids(i) for i in range(self.num_devices)],
-                seed=self.seed, num_workers=self.num_sampler_workers,
-                agg_kind=kind,
-                blk_caps=self._blk_caps if self._blk_caps else None,
-                residency=(self.store.core if self.gather_in_workers
-                           else None),
-                p3_full=self.algorithm == "p3",
-                feat_rows_cap=self._ring_rows_cap(),
-                worker_affinity=self.worker_affinity,
-                max_respawns=self.model_cfg.max_respawns,
-                straggler_timeout_s=self.model_cfg.straggler_timeout_s,
-                speculative=self.model_cfg.speculative_sampling,
-                fault_spec=self.model_cfg.fault_spec)
+            with self._setup_phase("pool_spawn"):
+                self._pool = SamplerPool(
+                    self.graph, self.model_cfg,
+                    [self._train_ids(i) for i in range(self.num_devices)],
+                    seed=self.seed, num_workers=self.num_sampler_workers,
+                    agg_kind=kind,
+                    blk_caps=self._blk_caps if self._blk_caps else None,
+                    residency=(self.store.core if self.gather_in_workers
+                               else None),
+                    p3_full=self.algorithm == "p3",
+                    feat_rows_cap=self._ring_rows_cap(),
+                    worker_affinity=self.worker_affinity,
+                    max_respawns=self.model_cfg.max_respawns,
+                    straggler_timeout_s=self.model_cfg.straggler_timeout_s,
+                    speculative=self.model_cfg.speculative_sampling,
+                    fault_spec=self.model_cfg.fault_spec)
         return self._pool
 
     def _ring_rows_cap(self) -> Optional[int]:
@@ -904,6 +945,7 @@ class SyncGNNTrainer:
         groups = list(sched.iterations(schedule))
         run_groups = groups[self._epoch_iter:] if resume else groups
         t0 = time.time()
+        self._compiles0 = self._compiles.compiles
         pstats = self._pstats = PipelineStats()
         # the scheduling core streams the epoch's batch source — one unit
         # per iteration group, tasks addressed by pure RNG coordinates
@@ -967,7 +1009,8 @@ class SyncGNNTrainer:
         n_batches = 0
         if self.pipeline:
             prepared_iter = PrefetchExecutor(
-                prepare, self.prefetch_depth, pstats).run(items)
+                prepare, self.prefetch_depth, pstats,
+                first_iteration=self._iter_no).run(items)
             # backpressure: at most prefetch_depth dispatched-but-unfinished
             # steps, else a fast host would pile up live input buffers
             inflight: deque = deque()
@@ -1024,7 +1067,11 @@ class SyncGNNTrainer:
                  for k in ("respawns", "resubmissions", "speculative",
                            "duplicates_dropped", "stale_results",
                            "crc_failures",
-                           "degraded_tasks", "recovery_s")}
+                           "degraded_tasks", "recovery_s", "sample_s",
+                           "layout_s", "ship_s", "decode_s")}
+        if "compile" not in self.setup_phase_s:
+            self.setup_phase_s["compile"] = (self._compiles.seconds
+                                             - self._compile_s0)
         return {**metrics, "epoch_time_s": wall, "batches": n_batches,
                 "pool_respawns": recov["respawns"],
                 "pool_resubmissions": recov["resubmissions"],
@@ -1054,6 +1101,17 @@ class SyncGNNTrainer:
                 "load_imbalance": self._balancer.imbalance(),
                 "host_produce_s": pstats.produce_s,
                 "host_wait_s": pstats.wait_s,
+                # the feed by stage: the prefetch thread's wait for its
+                # next item, less the ring decode it ran (its own line);
+                # the main thread's step dispatch; the sampling stages,
+                # summed over the workers (or run in this process)
+                "host_source_wait_s": pstats.source_wait_s,
+                "host_decode_s": recov["decode_s"],
+                "host_dispatch_s": pstats.dispatch_s,
+                "pool_sample_s": recov["sample_s"] + pstats.sample_s,
+                "pool_layout_s": recov["layout_s"] + pstats.layout_s,
+                "pool_ship_s": recov["ship_s"],
+                "compiles": self._compiles.compiles - self._compiles0,
                 # stage-2 split: time the TRAINING PROCESS spent gathering
                 # (in-process) or placing (worker-gathered) feature rows,
                 # and the ring traffic the offload cost per iteration

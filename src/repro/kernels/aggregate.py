@@ -139,6 +139,7 @@ def aggregate_blockcsr(blocks: jax.Array, cols: jax.Array, h_in: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_dstb * BLK, F_pad), h_in.dtype),
         interpret=interpret,
+        name="agg_blockcsr",
     )(cols, blocks, h_in)
     return out[:, :F] if F_pad != F else out
 
@@ -504,6 +505,7 @@ def aggregate_edges(tile_off: jax.Array, val: jax.Array, seg: jax.Array,
         scratch_shapes=[pltpu.VMEM((BLK, F_pad), jnp.float32),
                         *_stream_scratch(h_k)],
         interpret=interpret,
+        name="agg_edges",
     )(*_stream_operands(tile_off, val, seg, cols, h_k))
     return out[:, :F] if F_pad != F else out
 
@@ -744,6 +746,7 @@ def aggregate_fused(tile_off: jax.Array, val: jax.Array, seg: jax.Array,
         scratch_shapes=[pltpu.VMEM((BLK, F_pad), jnp.float32),  # aggregate
                         *_stream_scratch(h_k)],
         interpret=interpret,
+        name="agg_fused_fwd",
     )(*operands)
     return out[:, :N] if N_pad != N else out
 
@@ -799,6 +802,7 @@ def _fused_bwd_call(tile_off, val, seg, cols, h_in, g, w, b, s, *, act,
         out_shape=out_shapes,
         scratch_shapes=scratch + _stream_scratch(h_k),
         interpret=interpret,
+        name="agg_fused_bwd",
     )(*operands)
     outs = list(outs)
     dw = outs.pop(0)[:F, :N]
@@ -925,6 +929,7 @@ def _fused_bwd_merged_call(tile_off, val, seg, cols, tile_off_t, val_t,
                         pltpu.SemaphoreType.DMA(()),
                         *_stream_scratch(h_k)],
         interpret=interpret,
+        name="agg_fused_bwd_merged",
     )(*operands)
     outs = list(outs)
     dw = outs.pop(0)[:F, :N]
