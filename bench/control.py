@@ -12,11 +12,12 @@ coordinates) and runs ``bench/reference.train_steps`` in float32 at
 float32 ones. For a serving cell, each seed packs the cell's first
 requests into micro-batches of about ``--pack`` targets, samples them as
 the server does, and compares ``bench/reference.logits`` in bfloat16 with
-float32 by ``bench/compare.logit_gap``. One JSON line per seed, with the
-readings of the faults planted in the reference put in the program's
-place (training: the loss over half of each batch). A limit has to fail
-this control. The benchmark's own runs do not run it; it runs
-on the chip, at the cell's size.
+float32 by ``bench/compare.logit_gap``, all micro-batches in one pass
+(``bench/reference.stack_batches``) as the cell's run checks them. One
+JSON line per seed, with the readings of the faults planted in the
+reference put in the program's place (training: the loss over half of
+each batch). A limit has to fail this control. The benchmark's own runs
+do not run it; it runs on the chip, at the cell's size.
 """
 import argparse
 import json
@@ -60,12 +61,11 @@ def training_control(config: dict, traffic: dict, seed: int,
              for g in steps]
     f_in = arrays["features"].shape[1]
     params0 = reference.make_params(
-        m["name"], f_in, int(m["hidden"]),
-        int(config["dataset"]["num_classes"]), int(m["num_layers"]), seed)
-    ref = reference.train_steps(m["name"], params0, steps, feats, opt)
-    low = reference.train_steps(m["name"], params0, steps, feats, opt,
+        m, f_in, int(config["dataset"]["num_classes"]), seed)
+    ref = reference.train_steps(m, params0, steps, feats, opt)
+    low = reference.train_steps(m, params0, steps, feats, opt,
                                 dtype=jnp.bfloat16)
-    half = reference.train_steps(m["name"], params0, steps, feats, opt,
+    half = reference.train_steps(m, params0, steps, feats, opt,
                                  target_share=0.5)
 
     def numbers(run):
@@ -94,13 +94,12 @@ def serving_control(config: dict, traffic: dict, seed: int, arrays: dict,
                               graph.train_ids, 0, seed)
     ladder = bucket_ladder(int(m["batch_targets"]))
     _, ids = schedule(traffic, 30.0, seed, graph.num_vertices)
-    params = reference.make_params(
-        m["name"], graph.features.shape[1], int(m["hidden"]),
-        graph.num_classes, int(m["num_layers"]), seed)
-    worst, lo = 0.0, 0
+    params = reference.make_params(m, graph.features.shape[1],
+                                   graph.num_classes, seed)
+    sampled, counts, lo = [], [], 0
     for rid in range(batches):
         group, n = [], 0
-        while lo < len(ids) and n + len(ids[lo]) <= pack:
+        while lo < len(ids) and (not group or n + len(ids[lo]) <= pack):
             group.append(ids[lo])
             n += len(ids[lo])
             lo += 1
@@ -108,12 +107,14 @@ def serving_control(config: dict, traffic: dict, seed: int, arrays: dict,
         bucket = next(b for b in ladder if b >= len(flat))
         mb = sampler.request_batch(SERVE_EPOCH, rid,
                                    flat[np.arange(bucket) % len(flat)])
-        b = plain_batch(mb)
-        feats = np.asarray(arrays["features"][b["nodes"][0]])
-        ref = reference.logits(m["name"], params, b, feats)
-        low = reference.logits(m["name"], params, b, feats, jnp.bfloat16)
-        worst = max(worst, compare.logit_gap(low[:len(flat)],
-                                             ref[:len(flat)]))
+        sampled.append(plain_batch(mb))
+        counts.append(len(flat))
+    union, starts = reference.stack_batches(sampled)
+    feats = np.asarray(arrays["features"][union["nodes"][0]])
+    ref = reference.logits(m, params, union, feats)
+    low = reference.logits(m, params, union, feats, jnp.bfloat16)
+    worst = max(compare.logit_gap(low[a:a + n], ref[a:a + n])
+                for a, n in zip(starts, counts))
     return {"logit_gap": worst}, {}
 
 

@@ -24,8 +24,9 @@ waited for, ``drain_s`` at most; one that never comes counts in
 
 Check: a sample of micro-batches drawn from ``--seed`` (the one with the
 most targets always in it); each one's sampled neighbourhood is held to
-the graph, run through ``bench/reference.logits``, and every request in it
-is compared with what its future returned.
+the graph; the sample goes through ``bench/reference.logits`` as one
+batch (``bench/reference.stack_batches``), and every request in it is
+compared with what its future returned.
 """
 from __future__ import annotations
 
@@ -135,9 +136,8 @@ def run(run) -> dict:
                   arrays["labels"], arrays["train_ids"],
                   int(cfg["dataset"]["num_classes"]), cfg["dataset"]["name"])
     model_cfg = model_config_from(cfg)
-    params = reference.make_params(
-        m["name"], graph.features.shape[1], int(m["hidden"]),
-        graph.num_classes, int(m["num_layers"]), run.seed)
+    params = reference.make_params(m, graph.features.shape[1],
+                                   graph.num_classes, run.seed)
     params_np = jax.tree.map(np.asarray, params)
     due, ids = schedule(traffic, run.seconds, run.seed, graph.num_vertices)
     server = serve(model_cfg, graph=graph, params=params,
@@ -196,24 +196,29 @@ def run(run) -> dict:
         summary = tr.summarize(*tr.load_events(tr.find_xplane(
             run.trace_dir)), devices=run.chips)
 
+    t_check = time.perf_counter()
     fanouts = list(m["fanouts"])[::-1]
     problems = [f"request {j}: never answered" for j in failed]
     worst = 0.0
-    for b, lengths, answers in check:
+    for b, _, _ in check:
         problems += reference.check_batch(arrays, fanouts, b)
-        ref = reference.logits(m["name"], params_np, b,
-                               np.asarray(arrays["features"][b["nodes"][0]]))
-        lo = 0
-        for n, ans in zip(lengths, answers):
-            if ans is not None:
-                worst = max(worst, compare.logit_gap(ans, ref[lo:lo + n]))
-            lo += n
+    if check:
+        union, starts = reference.stack_batches([b for b, _, _ in check])
+        ref = reference.logits(m, params_np, union, np.asarray(
+            arrays["features"][union["nodes"][0]]))
+        for (_, lengths, answers), lo in zip(check, starts):
+            for n, ans in zip(lengths, answers):
+                if ans is not None:
+                    worst = max(worst, compare.logit_gap(ans,
+                                                         ref[lo:lo + n]))
+                lo += n
     correct, checks = compare.judge(
         {"logit_gap": worst},
         compare.load_limits(run.bench_dir, run.cell["name"]), problems)
     for p in problems[:20]:
         run.log(problem=p)
-    run.log(phase="check", logit_gap=worst, batches_checked=len(check))
+    run.log(phase="check", logit_gap=worst, batches_checked=len(check),
+            check_s=time.perf_counter() - t_check)
     return {"correct": correct, "attempted": len(due),
             "failed": len(failed), "checks": checks,
             "memory_peak_bytes": mem, "trace": summary,

@@ -74,16 +74,20 @@ def build_graph(run):
     return arrays, meta, Graph
 
 
+# keys of a configuration's ``model`` block that are the benchmark's and
+# not the program's: the precision the configuration states
+BENCH_MODEL_KEYS = ("precision",)
+
+
 def model_config_from(config: dict):
-    """The program's model configuration for a benchmark configuration."""
+    """The program's model configuration for a benchmark configuration:
+    every key of its ``model`` block but :data:`BENCH_MODEL_KEYS`, so a key
+    the program does not know fails here (``TypeError``)."""
     from repro.configs.gnn import GNNModelConfig, HostConfig
-    m = config["model"]
-    return GNNModelConfig(
-        m["name"], num_layers=int(m["num_layers"]), hidden=int(m["hidden"]),
-        fanouts=tuple(m["fanouts"]), batch_targets=int(m["batch_targets"]),
-        aggregate_backend=m["aggregate_backend"],
-        host=HostConfig(num_sampler_workers=int(
-            config["host"]["num_sampler_workers"])))
+    m = {k: v for k, v in config["model"].items()
+         if k not in BENCH_MODEL_KEYS}
+    return GNNModelConfig(**m, host=HostConfig(num_sampler_workers=int(
+        config["host"]["num_sampler_workers"])))
 
 
 def epoch_assignments(trainer):
@@ -127,9 +131,7 @@ def run(run) -> dict:
     run.log(phase="trainer")
     try:
         f_in, n_cls = graph.features.shape[1], graph.num_classes
-        params0 = reference.make_params(m["name"], f_in, int(m["hidden"]),
-                                        n_cls, int(m["num_layers"]),
-                                        run.seed)
+        params0 = reference.make_params(m, f_in, n_cls, run.seed)
         trainer.params = params0
         trainer.opt_state = trainer.optimizer.init(params0)
         params0 = jax_to_numpy(params0)
@@ -172,8 +174,8 @@ def run(run) -> dict:
                   "host_wait_s": sum(mt["host_wait_s"] for _, mt in window),
                   "epoch_time_s": sum(mt["epoch_time_s"]
                                       for _, mt in window),
-                  "model": m["name"], "dims": work.widths(
-                      f_in, int(m["hidden"]), n_cls, int(m["num_layers"]))}
+                  "model": m,
+                  "dims": work.model_widths(m, f_in, n_cls)}
         if run.trace:
             # the window's real work, batch by batch, from the trainer's
             # pure batch_at (seed, partition, epoch, index)
@@ -203,7 +205,7 @@ def run(run) -> dict:
                     arrays, fanouts, b)]
     feats = [[np.asarray(arrays["features"][b["nodes"][0]]) for b in group]
              for group in check]
-    ref = reference.train_steps(m["name"], params0, check, feats, opt)
+    ref = reference.train_steps(m, params0, check, feats, opt)
     b1 = float(opt["b1"])
     numbers = compare.training_numbers(
         prog_losses=[float(s[3]["loss"]) for s in steps],

@@ -13,41 +13,26 @@ every sampled edge is an in-edge of the graph, every destination keeps
 ``min(in-degree, fanout)`` distinct sources, the layer's vertex set is the
 destinations plus the sampled sources, and the labels are the graph's.
 
-Models (``h`` the layer input, ``agg`` the mean over the sampled in-edges,
-``s`` the destination's own row):
-
-* ``graphsage``: ``s @ w_self + agg @ w_neigh + b``
-* ``gcn``: ``(agg + s) @ w * 0.5 + b``
-
-ReLU between layers, softmax cross-entropy averaged over the targets,
-AdamW with global-norm clipping and a warm-up-then-cosine learning rate.
+Each model is a module of its own, ``bench/models/<name>.py``, found by
+the configuration's ``model.name`` (``bench/models/__init__.py`` says what
+it gives). ``model`` below is the configuration's ``model`` block. What the
+models share lives here: the weight rule, softmax cross-entropy averaged
+over the targets, AdamW with global-norm clipping and a warm-up-then-cosine learning rate.
 ``dtype`` float32 runs every matmul at ``highest`` precision; bfloat16
 (the control) rounds weights, features and every intermediate to it.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Sequence
+from typing import List, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench import models, work
+
 
 # -- weights -----------------------------------------------------------------
-
-def param_shapes(model: str, f_in: int, hidden: int, n_classes: int,
-                 num_layers: int) -> List[Dict[str, tuple]]:
-    dims = [f_in] + [hidden] * (num_layers - 1) + [n_classes]
-    out = []
-    for fi, fo in zip(dims[:-1], dims[1:]):
-        if model == "graphsage":
-            out.append({"w_self": (fi, fo), "w_neigh": (fi, fo), "b": (fo,)})
-        elif model == "gcn":
-            out.append({"w": (fi, fo), "b": (fo,)})
-        else:
-            raise ValueError(f"no reference for model {model!r}")
-    return out
-
 
 def seed_key(seed: int) -> jax.Array:
     """A PRNG key from any whole-number seed (all its bits count)."""
@@ -56,11 +41,12 @@ def seed_key(seed: int) -> jax.Array:
                               int(words[1]))
 
 
-def make_params(model: str, f_in: int, hidden: int, n_classes: int,
-                num_layers: int, seed: int) -> dict:
+def make_params(model: dict, f_in: int, n_classes: int, seed: int) -> dict:
     """Weights N(0, 1/fan_in) and biases N(0, 0.01^2), made on the default
-    device in one jitted call from ``seed``; float32."""
-    shapes = param_shapes(model, f_in, hidden, n_classes, num_layers)
+    device in one jitted call from ``seed``; float32. Within a layer the
+    leaves are drawn in the order of their sorted names."""
+    shapes = models.find(model["name"]).param_shapes(
+        model, work.model_widths(model, f_in, n_classes))
 
     def build(key):
         layers = []
@@ -159,6 +145,41 @@ def check_batch(graph: dict, fanouts: Sequence[int], b: dict) -> List[str]:
     return problems
 
 
+def stack_batches(batches: Sequence[dict]) -> tuple:
+    """Many batches as one block-diagonal batch, for one pass of the
+    reference over all of them, and the first target row of each.
+
+    Each layer's rows and edges are padded to the next power of two, so a
+    run meets few shapes and its eager operations compile once (a new shape
+    compiles every operation again). Padded edges join row 0 to a padded
+    destination row, which no real row reads; the rows a model computes
+    for a real destination are those it computes for the batch alone."""
+    L = len(batches[0]["edge_src"])
+    nodes = [np.concatenate([b["nodes"][l] for b in batches])
+             for l in range(L + 1)]
+    offsets = [np.cumsum([0] + [len(b["nodes"][l]) for b in batches])
+               for l in range(L + 1)]
+    rows = [1 << len(n).bit_length() for n in nodes]  # at least one spare
+    out = {"nodes": [np.pad(n, (0, r - len(n))) for n, r in zip(nodes, rows)],
+           "edge_src": [], "edge_dst": [], "self_idx": [],
+           "labels": np.pad(np.concatenate([b["labels"] for b in batches]),
+                            (0, rows[L] - len(nodes[L])))}
+    for l in range(L):
+        src = np.concatenate([b["edge_src"][l] + o for b, o in
+                              zip(batches, offsets[l])])
+        dst = np.concatenate([b["edge_dst"][l] + o for b, o in
+                              zip(batches, offsets[l + 1])])
+        edges = 1 << max(len(src) - 1, 0).bit_length()
+        out["edge_src"].append(np.pad(src, (0, edges - len(src))))
+        out["edge_dst"].append(np.pad(dst, (0, edges - len(dst)),
+                                      constant_values=rows[l + 1] - 1))
+        self_idx = np.concatenate([b["self_idx"][l] + o for b, o in
+                                   zip(batches, offsets[l])])
+        out["self_idx"].append(np.pad(self_idx,
+                                      (0, rows[l + 1] - len(self_idx))))
+    return out, offsets[L][:-1]
+
+
 # -- model ---------------------------------------------------------------------
 
 def _to_device(b: dict, feats: np.ndarray, dtype) -> dict:
@@ -171,29 +192,11 @@ def _to_device(b: dict, feats: np.ndarray, dtype) -> dict:
             "labels": jnp.asarray(b["labels"], jnp.int32)}
 
 
-def forward(model: str, params: dict, d: dict) -> jax.Array:
-    h = d["h"]
-    L = len(d["edge_src"])
-    for l in range(L):
-        p = params["layers"][l]
-        src, dst, n_dst = d["edge_src"][l], d["edge_dst"][l], d["n_dst"][l]
-        ones = jnp.ones(src.shape, h.dtype)
-        deg = jax.ops.segment_sum(ones, dst, num_segments=n_dst)
-        agg = (jax.ops.segment_sum(h[src], dst, num_segments=n_dst)
-               / jnp.maximum(deg, 1.0)[:, None])
-        s = h[d["self_idx"][l]]
-        if model == "graphsage":
-            h = s @ p["w_self"] + agg @ p["w_neigh"] + p["b"]
-        elif model == "gcn":
-            h = (agg + s) @ p["w"] * 0.5 + p["b"]
-        else:
-            raise ValueError(model)
-        if l != L - 1:
-            h = jax.nn.relu(h)
-    return h
+def forward(model: dict, params: dict, d: dict) -> jax.Array:
+    return models.find(model["name"]).forward(model, params, d)
 
 
-def loss(model: str, params: dict, d: dict, keep: int = 0) -> jax.Array:
+def loss(model: dict, params: dict, d: dict, keep: int = 0) -> jax.Array:
     """Mean cross-entropy over the targets (the first ``keep`` of them
     where ``keep`` is set: the half-batch fault)."""
     logits = forward(model, params, d).astype(jnp.float32)
@@ -209,7 +212,7 @@ def _precision(dtype):
     return "highest" if dtype == jnp.float32 else "default"
 
 
-def logits(model: str, params: dict, b: dict, feats: np.ndarray,
+def logits(model: dict, params: dict, b: dict, feats: np.ndarray,
            dtype=jnp.float32) -> np.ndarray:
     with jax.default_matmul_precision(_precision(dtype)):
         p = jax.tree.map(lambda x: jnp.asarray(x, dtype), params)
@@ -228,7 +231,7 @@ def lr_at(opt: dict, step: int) -> float:
     return base * (ratio + (1 - ratio) * 0.5 * (1 + np.cos(np.pi * t)))
 
 
-def train_steps(model: str, params0: dict, steps: List[List[dict]],
+def train_steps(model: dict, params0: dict, steps: List[List[dict]],
                 feats: List[List[np.ndarray]], opt: dict,
                 dtype=jnp.float32, target_share: float = 1.0) -> dict:
     """Run the data-parallel steps: each step averages the loss and the

@@ -54,9 +54,8 @@ def main(argv=None) -> int:
     graph = Graph(arrays["indptr"], arrays["indices"], arrays["features"],
                   arrays["labels"], arrays["train_ids"],
                   int(cfg["dataset"]["num_classes"]), cfg["dataset"]["name"])
-    params = reference.make_params(
-        m["name"], graph.features.shape[1], int(m["hidden"]),
-        graph.num_classes, int(m["num_layers"]), args.seed)
+    params = reference.make_params(m, graph.features.shape[1],
+                                   graph.num_classes, args.seed)
     slo = float(traffic["slo_ms"])
     with serve(model_config_from(cfg), graph=graph, params=params,
                algorithm=cfg["algorithm"], slo_ms=slo,

@@ -13,17 +13,23 @@ the first also needs the gradient of its input (the first layer's input is
 the feature matrix, which has no gradient). The aggregation's backward is
 the transposed aggregation at the layer's input width.
 
-Aggregation (a mean over sampled in-edges) needs ``2 * edges * f_in``
-operations and, as the least traffic, one read of each source row, one
-write of each destination row, and the edge's two int32 endpoints:
-``4 * f_in * (n_src + n_dst) + 8 * edges`` bytes.
+What is shared and what each model gives: the aggregation counts here are
+those of the mean over sampled in-edges, which every model so far uses and
+``agg_roofline.train`` reads. It needs ``2 * edges * f_in`` operations and,
+as the least traffic, one read of each source row, one write of each
+destination row, and the edge's two int32 endpoints:
+``4 * f_in * (n_src + n_dst) + 8 * edges`` bytes. A model's module
+(``bench/models/<name>.py``) gives the rest: its layer widths
+(:func:`model_widths`; :func:`widths` unless it says otherwise) and how many
+weight matrices per layer multiply the destinations' rows
+(``UPDATE_MATMULS``, 1 unless it says otherwise), which
+:func:`step_flops` counts beside the aggregation.
 """
 from __future__ import annotations
 
 from typing import List, Sequence, Tuple
 
-# weight matrices per layer that multiply an (n_dst, f_in) operand
-UPDATE_MATMULS = {"graphsage": 2, "gcn": 1}
+from bench import models
 
 
 def layer_sizes(node_counts: Sequence[int], edge_counts: Sequence[int]
@@ -37,6 +43,16 @@ def widths(f_in: int, hidden: int, n_classes: int, num_layers: int
            ) -> List[Tuple[int, int]]:
     dims = [f_in] + [hidden] * (num_layers - 1) + [n_classes]
     return list(zip(dims[:-1], dims[1:]))
+
+
+def model_widths(model: dict, f_in: int, n_classes: int
+                 ) -> List[Tuple[int, int]]:
+    """The layers' ``(f_in, f_out)`` for a configuration's ``model`` block."""
+    mod = models.find(model["name"])
+    if hasattr(mod, "widths"):
+        return mod.widths(model, f_in, n_classes)
+    return widths(f_in, int(model["hidden"]), n_classes,
+                  int(model["num_layers"]))
 
 
 def agg_flops(sizes, dims, train: bool = True) -> float:
@@ -59,9 +75,10 @@ def agg_bytes(sizes, dims, train: bool = True) -> float:
     return total
 
 
-def step_flops(model: str, sizes, dims, train: bool = True) -> float:
-    """Aggregation plus update matmuls; with ``train`` the backward too."""
-    k = UPDATE_MATMULS[model]
+def step_flops(model: dict, sizes, dims, train: bool = True) -> float:
+    """Aggregation plus the update matmuls of a configuration's ``model``
+    block; with ``train`` the backward too."""
+    k = getattr(models.find(model["name"]), "UPDATE_MATMULS", 1)
     total = agg_flops(sizes, dims, train)
     for l, ((_, nd, _), (fi, fo)) in enumerate(zip(sizes, dims)):
         mm = 2.0 * k * nd * fi * fo

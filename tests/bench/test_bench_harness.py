@@ -258,3 +258,18 @@ def test_the_bfloat16_control_is_not_correct(bench_dir):
         assert not ok, numbers
         assert not compare.judge(faults["half_batch"],
                                  LIMITS["tiny.train"])[0]
+
+
+def test_the_bfloat16_serving_control_is_not_correct(bench_dir):
+    """The serving control (bench/control.py): the reference's logits in
+    bfloat16 in the program's place fail the serving cell's limit. The pack
+    is smaller than the largest request, which then makes a micro-batch of
+    its own."""
+    sys.path.insert(0, str(REPO / "src"))
+    from bench import compare, control
+    arrays, _ = graphs.load(TINY["dataset"])
+    for seed in (2**32 + 3,):
+        numbers, _ = control.serving_control(TINY, SERVE, seed, arrays,
+                                             pack=3,
+                                             batches=8)
+        assert not compare.judge(numbers, LIMITS["tiny.serve"])[0], numbers

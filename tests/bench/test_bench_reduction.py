@@ -75,10 +75,10 @@ def test_work_counts_by_hand():
         + 2 * (4 * 8 * 6 + 40)
     # graphsage: two matmuls per layer; fwd + dW everywhere, dX above 0
     mm0, mm1 = 2 * 2 * 4 * 3 * 8, 2 * 2 * 2 * 8 * 5
-    assert work.step_flops("graphsage", sizes, dims) == \
+    assert work.step_flops({"name": "graphsage"}, sizes, dims) == \
         work.agg_flops(sizes, dims) + 2 * mm0 + 3 * mm1
     mm0, mm1 = 2 * 4 * 3 * 8, 2 * 2 * 8 * 5
-    assert work.step_flops("gcn", sizes, dims, train=False) == \
+    assert work.step_flops({"name": "gcn"}, sizes, dims, train=False) == \
         work.agg_flops(sizes, dims, train=False) + mm0 + mm1
 
 
@@ -134,7 +134,7 @@ def test_reference_forward_by_hand():
                      "b": np.zeros(2, np.float32)},
                     {"w": np.ones((2, 3), np.float32),
                      "b": np.array([0.0, 1.0, 2.0], np.float32)}]}
-    out = reference.logits("gcn", p, b, feats)
+    out = reference.logits({"name": "gcn"}, p, b, feats)
     h = feats
     agg0 = np.stack([h[1], h[0], h[0], h[0]])
     h1 = np.maximum((agg0 + h) * 0.5, 0)
@@ -147,11 +147,12 @@ def test_reference_training_steps_move_every_leaf():
     b = _tiny_batch()
     feats = np.random.default_rng(0).standard_normal((4, 2)).astype(
         np.float32)
-    params = reference.make_params("graphsage", 2, 4, 3, 2, seed=2**33 + 5)
+    model = {"name": "graphsage", "hidden": 4, "num_layers": 2}
+    params = reference.make_params(model, 2, 3, seed=2**33 + 5)
     opt = {"lr": 0.01, "b1": 0.9, "b2": 0.95, "eps": 1e-8,
            "weight_decay": 0.0, "grad_clip": 1.0, "warmup": 10,
            "total": 1000, "min_ratio": 0.1}
-    out = reference.train_steps("graphsage", params, [[b], [b]],
+    out = reference.train_steps(model, params, [[b], [b]],
                                 [[feats], [feats]], opt)
     assert len(out["loss"]) == 2 and out["loss"][1] < out["loss"][0]
     p0 = [np.asarray(x) for x in __import__("jax").tree.leaves(params)]
@@ -212,3 +213,26 @@ def test_zipf_ranks_favour_the_head():
     r = serve_open_loop.zipf_ranks(0.99, 100_000, 50_000, seed=1)
     assert r.min() >= 0 and r.max() < 100_000
     assert np.mean(r == 0) > 0.05 and np.mean(r < 100) > 0.3
+
+
+def test_stacked_batches_give_each_batch_its_own_logits():
+    b1 = _tiny_batch()
+    b2 = {"nodes": [np.array([0, 2, 3]), np.array([0, 3]), np.array([3])],
+          "edge_src": [np.array([1, 2, 0]), np.array([0])],
+          "edge_dst": [np.array([0, 0, 1]), np.array([0])],
+          "self_idx": [np.array([0, 2]), np.array([1])],
+          "labels": np.array([0])}
+    union, starts = reference.stack_batches([b1, b2, b1])
+    # rows and edges padded to powers of two, with a spare row per layer
+    assert [len(n) for n in union["nodes"]] == [16, 16, 8]
+    assert [len(e) for e in union["edge_src"]] == [16, 16]
+    assert list(starts) == [0, 2, 3]
+    model = {"name": "graphsage", "hidden": 4, "num_layers": 2}
+    params = reference.make_params(model, 2, 3, seed=11)
+    feats = np.random.default_rng(1).standard_normal((5, 2)).astype(
+        np.float32)
+    got = reference.logits(model, params, union, feats[union["nodes"][0]])
+    for b, lo in zip([b1, b2, b1], starts):
+        want = reference.logits(model, params, b, feats[b["nodes"][0]])
+        np.testing.assert_allclose(got[lo:lo + len(want)], want, rtol=1e-6,
+                                   atol=1e-7)
